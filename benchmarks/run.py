@@ -759,7 +759,7 @@ def _serve_leg_ops(rate: float) -> int:
     return int(np.clip(rate * SERVE_LEG_S, 1000, N_SERVE_OPS))
 
 
-def _serve_index(keys, *, background: bool, telemetry: bool):
+def serve_index(keys, *, background: bool, telemetry: bool):
     from repro.api import IndexConfig, LearnedIndex, MaintenanceConfig
     return LearnedIndex.build(keys, config=IndexConfig(
         engine=ENGINE, sample_stride=4, overlay_cap=8192,
@@ -767,7 +767,7 @@ def _serve_index(keys, *, background: bool, telemetry: bool):
         telemetry=telemetry))
 
 
-def _warm_serve_buckets(ix, keys, cfg) -> None:
+def warm_serve_buckets(ix, keys, cfg) -> None:
     """Mint every read-path executable the coalescer can reach (pow2
     lane buckets from one request up to the batch cap) before the timed
     legs, then anchor the retrace watchdog."""
@@ -779,6 +779,29 @@ def _warm_serve_buckets(ix, keys, cfg) -> None:
                  max_hits=cfg.max_hits)
         b *= 2
     ix.telemetry.mark_warm()
+
+
+def journal_replay_check(served, journal, keys, tag: str) -> dict:
+    """The serving oracle leg: replay a served run's commit-order journal
+    through `WorkloadRunner` on a fresh index over the same `keys`.  The
+    replay diffs every batch against `SortedOracle` and raises on any
+    divergence (strict, CI-visible); the fresh index's final items() must
+    then equal the served index's bit-exactly, or this raises too."""
+    from repro.workloads import WorkloadRunner
+    fresh = serve_index(keys, background=False, telemetry=False)
+    try:
+        wrep = WorkloadRunner(fresh).run(journal, name=f"{tag},replay")
+        k1, v1 = served.items()
+        k2, v2 = fresh.items()
+    finally:
+        fresh.close()
+    if not (np.array_equal(k1, k2) and np.array_equal(v1, v2)):
+        raise AssertionError(
+            f"{tag}: concurrent run's final items() diverged from its "
+            f"own journal's deterministic replay")
+    return dict(n_divergences=len(wrep.divergences),
+                journal_batches=len(journal), checked_ops=wrep.n_ops,
+                bit_exact=True)
 
 
 def _leg_brief(rep) -> dict:
@@ -815,7 +838,7 @@ def serve_bench(preset: str) -> dict:
     by the batcher into pow2 facade batches."""
     from repro.serve import ServeConfig, ServeFrontend, open_loop, \
         saturation_search
-    from repro.workloads import PRESETS, WorkloadRunner, generate_stream
+    from repro.workloads import PRESETS, generate_stream
     keys = workload_universe()
     spec = PRESETS[preset].scaled(n_ops=N_SERVE_OPS,
                                   batch_size=N_SERVE_REQ_OPS)
@@ -830,9 +853,9 @@ def serve_bench(preset: str) -> dict:
     print(f"# serve: {preset} on the '{ENGINE}' engine "
           f"({N_SERVE_CLIENTS} open-loop client streams, "
           f"{N_SERVE_REQ_OPS}-op requests)")
-    ix = _serve_index(keys, background=bg_main,
+    ix = serve_index(keys, background=bg_main,
                       telemetry=bool(METRICS_JSON) or bool(TRACE_JSON))
-    _warm_serve_buckets(ix, keys, scfg)
+    warm_serve_buckets(ix, keys, scfg)
     tap = _StreamTap(spec, keys)
     fe = ServeFrontend(ix, scfg, journal=False)
 
@@ -877,33 +900,20 @@ def serve_bench(preset: str) -> dict:
     # -- oracle equivalence: journaled 50%-rate run, replayed ----------------
     print(f"# serve: {preset} oracle equivalence "
           f"(journal replay on a fresh index)")
-    served = _serve_index(keys, background=bg_main, telemetry=False)
-    _warm_serve_buckets(served, keys, scfg)
+    served = serve_index(keys, background=bg_main, telemetry=False)
+    warm_serve_buckets(served, keys, scfg)
     fe = ServeFrontend(served, scfg, journal=True)
     ostream = generate_stream(spec.scaled(seed=spec.seed + 977), keys)
     orep = open_loop(fe, ostream, max(0.5 * sat, SERVE_START_RATE),
                      n_clients=N_SERVE_CLIENTS)
     journal = fe.journal_batches()
     fe.close()
-    fresh = _serve_index(keys, background=False, telemetry=False)
-    # strict replay: any batch-level oracle divergence raises out of the
-    # bench run (CI-visible), same policy as workload_bench
-    wrep = WorkloadRunner(fresh).run(journal, name=f"{tag},replay")
-    k1, v1 = served.items()
-    k2, v2 = fresh.items()
-    bit_exact = bool(np.array_equal(k1, k2) and np.array_equal(v1, v2))
-    served.close()
-    fresh.close()
-    if not bit_exact:
-        raise AssertionError(
-            f"{tag}: concurrent run's final items() diverged from its "
-            f"own journal's deterministic replay")
-    sec["oracle"] = dict(n_divergences=len(wrep.divergences),
-                         journal_batches=len(journal),
-                         checked_ops=wrep.n_ops, bit_exact=bit_exact,
+    sec["oracle"] = dict(journal_replay_check(served, journal, keys, tag),
                          shed_frac=orep.shed_frac)
-    csv_row(f"{tag},{ENGINE},oracle_divergences", len(wrep.divergences),
-            f"journal_batches={len(journal)};bit_exact={bit_exact}")
+    served.close()
+    csv_row(f"{tag},{ENGINE},oracle_divergences",
+            sec["oracle"]["n_divergences"],
+            f"journal_batches={len(journal)};bit_exact=True")
 
     # -- background vs sync maintenance under identical load -----------------
     sec["maintenance_compare"] = {}
@@ -912,8 +922,8 @@ def serve_bench(preset: str) -> dict:
     cmp_rate = 0.8 * sat
     for label, bg in modes:
         print(f"# serve: {preset} maintenance={label} at 80% saturation")
-        cix = _serve_index(keys, background=bg, telemetry=False)
-        _warm_serve_buckets(cix, keys, scfg)
+        cix = serve_index(keys, background=bg, telemetry=False)
+        warm_serve_buckets(cix, keys, scfg)
         cfe = ServeFrontend(cix, scfg, journal=False)
         ctap = _StreamTap(spec.scaled(seed=spec.seed + 1531), keys)
         # 2x leg length: the comparison must cross the overlay-cap merge
@@ -1189,6 +1199,8 @@ def main() -> None:
                          "back-to-back (the zipfian splice-locality "
                          "ablation)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_persistent_cache
+    enable_persistent_cache()
     global ENGINE, METRICS_JSON, TRACE_JSON
     ENGINE = args.engine
     METRICS_JSON = args.metrics_json

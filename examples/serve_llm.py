@@ -5,9 +5,13 @@ session table on the admission/KV-slot path (Algorithms 7/8 in serving).
 """
 import argparse
 import dataclasses
+import os
 import time
 
-import jax
+# the session table's index keys are f64; the model uses explicit dtypes
+os.environ.setdefault("JAX_ENABLE_X64", "1")
+
+import jax  # noqa: E402
 import jax.numpy as jnp
 import numpy as np
 

@@ -38,7 +38,9 @@ class IndexConfig:
                         (VMEM kernel dispatch with XLA fallback, f32 keys),
                         or "sharded" (mesh + per-shard overlays).
     dtype             : key/model dtype; None picks the engine default
-                        (f64 for local/sharded, f32 for pallas).
+                        (f64 for local/sharded, f32 for pallas).  A
+                        64-bit dtype needs `jax_enable_x64`; building
+                        without it raises.
     pad               : pow2-pad device tables so republishes reuse the
                         compiled search executable.
     merge             : `repro.online.MergePolicy` deciding when pending
@@ -56,13 +58,16 @@ class IndexConfig:
     mesh_axis         : mesh axis name for the sharded engine.
     lookup_strategy   : sharded lookup collective: "gather" (exact) or
                         "a2a" (capacity-bounded buckets).
-    interpret         : Pallas interpret mode; None = interpret off-TPU.
+    interpret         : Pallas interpret mode; None = follow the backend
+                        (compiled on a TPU, interpreted elsewhere); True
+                        on a TPU raises.
     vmem_budget_bytes : table-size ceiling for the kernel path; bigger
                         snapshots dispatch to the XLA fallback.
     early_exit        : batch-convergence early exit (local engine; the
-                        sharded engine always runs the fixed-trip scan —
-                        jax 0.4.x shard_map has no while_loop replication
-                        rule — and the kernel path is fixed-trip by design).
+                        sharded engine always runs the fixed-trip scan,
+                        since shard_map's varying-axes check refuses the
+                        early-exit while_loop's carry, and the kernel walks
+                        each query only until it resolves).
     max_hits          : default per-query range-window bound.
     telemetry         : enable per-op latency histograms + merge-pipeline
                         trace spans (`repro.obs`, DESIGN.md section 13).

@@ -280,6 +280,17 @@ class EngineTelemetryBase:
                          fill=ov["overlay_fill"]))
 
 
+def _require_x64(cfg: IndexConfig) -> None:
+    """A 64-bit key dtype needs `jax_enable_x64`: without it jax narrows
+    f64 keys and int64 payloads to 32 bits without an error."""
+    if (np.dtype(cfg.resolved_dtype).itemsize == 8
+            and not jax.config.jax_enable_x64):
+        raise ValueError(
+            f"the {cfg.engine} engine's {np.dtype(cfg.resolved_dtype).name} "
+            f"keys need jax_enable_x64 (set JAX_ENABLE_X64=1 before jax is "
+            f"imported); without it jax truncates them to 32 bits")
+
+
 def _reject_background(cfg: IndexConfig, engine: str) -> None:
     if cfg.maintenance is not None and cfg.maintenance.background:
         raise ValueError(
@@ -327,10 +338,12 @@ def _pair_table_recheck(pk, pv, q, v, f):
     where f32 ULP-safety is unattainable (DESIGN.md section 7) a boundary
     query can then mis-route by one child and miss.  Found lanes are always
     true hits (tag + key equality), so only misses need the O(log n)
-    bisection of the key-sorted pair table."""
+    bisection of the key-sorted pair table.  Also returns how many lanes
+    it patched: a search that misroutes more than rarely shows there."""
     i = jnp.clip(jnp.searchsorted(pk, q), 0, pk.shape[0] - 1)
     hit = pk[i] == q
-    return jnp.where(f, v, jnp.where(hit, pv[i], v)), f | hit
+    patched = jnp.sum(hit & ~f, dtype=jnp.int32)
+    return jnp.where(f, v, jnp.where(hit, pv[i], v)), f | hit, patched
 
 
 watchdog.register_jit("api.pair_table_recheck", _pair_table_recheck)
@@ -393,6 +406,7 @@ class LocalEngine(EngineTelemetryBase):
     name = "local"
 
     def __init__(self, keys: np.ndarray, vals: np.ndarray, cfg: IndexConfig):
+        _require_x64(cfg)
         self.cfg = cfg
         self.telemetry = Telemetry(enabled=cfg.telemetry)
         self.oi = OnlineIndex(keys, vals, policy=cfg.merge,
@@ -579,6 +593,10 @@ class PallasEngine(EngineTelemetryBase):
         self._timings: list[dict] = []
         self._writes_since_publish = 0
         self._writes_since_pressure = 0
+        # lookup batches per dispatch route (kernels.ops.ROUTE_*), and the
+        # lanes the pair-table recheck had to patch after the search
+        self.routes: dict[str, int] = {}
+        self.recheck_patched_lanes = 0
         self._publish()
 
     def _n_forced_full_flattens(self) -> int:
@@ -626,12 +644,6 @@ class PallasEngine(EngineTelemetryBase):
                 f"full f64 key precision.", count=n_collapsed)
         return k32[keep].astype(np.float64), vals[keep]
 
-    @property
-    def _interpret(self) -> bool:
-        if self.cfg.interpret is not None:
-            return self.cfg.interpret
-        return jax.default_backend() != "tpu"
-
     def _publish(self, merge_s: float = 0.0):
         t0 = time.perf_counter()
         with self.telemetry.span("merge.flatten"):
@@ -677,10 +689,14 @@ class PallasEngine(EngineTelemetryBase):
 
     def lookup(self, queries):
         q32 = jnp.asarray(np.asarray(queries, np.float64), jnp.float32)
-        v, f = self._K.dili_search(self.arrs, q32, interpret=self._interpret,
-                                   vmem_budget=self.cfg.vmem_budget_bytes)
-        v, f = _pair_table_recheck(self.snap.arrays["pair_key"],
-                                   self.snap.arrays["pair_val"], q32, v, f)
+        v, f = self._K.dili_search(self.arrs, q32,
+                                   interpret=self.cfg.interpret,
+                                   vmem_budget=self.cfg.vmem_budget_bytes,
+                                   routes=self.routes)
+        v, f, patched = _pair_table_recheck(self.snap.arrays["pair_key"],
+                                            self.snap.arrays["pair_val"],
+                                            q32, v, f)
+        self.recheck_patched_lanes += int(patched)
         if self.overlay.count:
             if self._ov_mirror is None:
                 self._ov_mirror = overlay_device_arrays(self.overlay,
@@ -832,6 +848,8 @@ class PallasEngine(EngineTelemetryBase):
                     table_bytes=self._K.table_bytes(self.arrs),
                     kernel_eligible=(self._K.table_bytes(self.arrs)
                                      <= self.cfg.vmem_budget_bytes),
+                    kernel_routes=dict(self.routes),
+                    recheck_patched_lanes=self.recheck_patched_lanes,
                     device_bytes=self.snap.nbytes)
 
 
@@ -850,6 +868,7 @@ class ShardedEngine(EngineTelemetryBase):
     name = "sharded"
 
     def __init__(self, keys: np.ndarray, vals: np.ndarray, cfg: IndexConfig):
+        _require_x64(cfg)
         self.cfg = cfg
         self.telemetry = Telemetry(enabled=cfg.telemetry)
         _reject_background(cfg, self.name)
@@ -1112,6 +1131,7 @@ class ShardedEngine(EngineTelemetryBase):
                     snapshot_keys=sum(int(f.n_pairs) for f in self.sd.flats),
                     per_shard_pending=[ov.count for ov in self.sd.overlays],
                     n_publishes=self.n_publishes,
+                    device_ids=[int(d.id) for d in self.mesh.devices.flat],
                     device_bytes=sum(int(np.prod(v.shape)) * v.dtype.itemsize
                                      for v in self.arrs.values()))
 

@@ -7,9 +7,9 @@ children live on different chips.
 
 Two lookup strategies:
   * ``gather``  (default, always correct): all_gather the query batch, search
-    locally, psum_scatter masked results back.  Collective bytes:
-    Q*8 gathered + Q*8 reduced per chip — bandwidth-roofline analyzed in
-    benchmarks/roofline.py.
+    locally, return each result from its owner shard (`_owner_scatter`: an
+    all_to_all plus a select).  Collective bytes: Q*8 gathered + Q*8
+    returned per chip.
   * ``a2a``     (optimized, capacity-bounded): bucket queries by shard,
     all_to_all fixed-capacity buckets, search, all_to_all back.  Bytes:
     2*C*R*8 per chip with C = capacity per (src, dst) pair.  Falls back to
@@ -153,8 +153,9 @@ def _local_search(local_idx: dict, q: jnp.ndarray, max_depth: int,
     idx["root"] = local_idx["root"][0]
     idx["max_depth"] = max_depth
     idx["has_dense"] = has_dense       # static: skips the dense probe phases
-    # depth-exact fixed-trip scan: shard_map has no replication rule for
-    # while_loop (jax 0.4.x), so the early-exit variant stays host-side
+    # depth-exact fixed-trip scan: shard_map's varying-axes check refuses
+    # the early-exit while_loop, whose initial carry is not marked varying
+    # over the mesh axis, so the early-exit variant stays off the mesh
     return S.search_batch(idx, q, max_depth=max_depth)
 
 
@@ -207,6 +208,33 @@ watchdog.register_jit_provider("distributed.collectives",
                                _collective_cache_sizes)
 
 
+def _owner_scatter(own: jnp.ndarray, xs: tuple, axis: str,
+                   n_shards: int) -> tuple[list, jnp.ndarray]:
+    """Each device's slice of every `x` in `xs` ([Q_total, ...] on every
+    shard), taken from the one shard whose `own` mask is set for that
+    element (zero where none is), plus the slice of that mask: whether
+    any shard owned the element.
+
+    This is the reduce-scatter of the masked values, written as an
+    all_to_all plus a select: XLA:TPU has no 64-bit reduce-scatter (and
+    cannot split an f64 into 32-bit words either), while all_to_all moves
+    any dtype.  The bytes moved are the reduce-scatter's plus one int32
+    mask, and the select is bit-exact."""
+    def a2a(v):                  # [Q_total, ...] -> [R, Q_total / R, ...]
+        v = jax.lax.all_to_all(v, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
+        return v.reshape((n_shards, -1) + v.shape[1:])
+    owns = a2a(own.astype(jnp.int32)) > 0
+    outs = []
+    for x in xs:
+        xr = a2a(x)
+        out = jnp.zeros(xr.shape[1:], x.dtype)
+        for r in range(n_shards):
+            out = jnp.where(owns[r], xr[r], out)
+        outs.append(out)
+    return outs, owns.any(axis=0)
+
+
 def sharded_lookup(mesh: Mesh, sd_arrays: dict, queries: jnp.ndarray,
                    max_depth: int, axis: str = "data",
                    strategy: str = "gather", overlay: dict | None = None,
@@ -217,8 +245,6 @@ def sharded_lookup(mesh: Mesh, sd_arrays: dict, queries: jnp.ndarray,
     shard_map body — snapshot traversal + overlay searchsorted are one fused
     device dispatch, with no host round-trip between them.  Each query's
     overlay state is applied by the one shard that owns its key range."""
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[axis]
     bounds = sd_arrays["boundaries"]
     ov = overlay if overlay is not None else _empty_overlay(bounds.dtype)
@@ -238,15 +264,11 @@ def sharded_lookup(mesh: Mesh, sd_arrays: dict, queries: jnp.ndarray,
             v, f = S.resolve_overlay(ovr, q_all, v, f)
             # mask to own range: boundaries[r] <= q < boundaries[r+1]
             own = (q_all >= bnd[r]) & (q_all < bnd[r + 1])
-            v = jnp.where(own & f, v, 0)
-            f = own & f
-            # sum across shards, scatter back each device's slice
-            v = jax.lax.psum_scatter(v, axis, scatter_dimension=0, tiled=True)
-            f = jax.lax.psum_scatter(f.astype(jnp.int32), axis,
-                                     scatter_dimension=0, tiled=True)
-            return v, f > 0
+            # each device's slice, from the shard owning each query's key
+            (v,), f = _owner_scatter(own & f, (v,), axis, n_shards)
+            return v, f
 
-        fn = _cached_collective(cache_key, lambda: shard_map(
+        fn = _cached_collective(cache_key, lambda: jax.shard_map(
             body, mesh=mesh,
             in_specs=(in_specs, P(), ov_specs, P(axis)),
             out_specs=(P(axis), P(axis))))
@@ -287,7 +309,7 @@ def sharded_lookup(mesh: Mesh, sd_arrays: dict, queries: jnp.ndarray,
             inv = jnp.argsort(order)
             return vs[inv], fs[inv], jnp.sum(~ok).astype(jnp.int32)[None]
 
-        fn = _cached_collective(cache_key, lambda: shard_map(
+        fn = _cached_collective(cache_key, lambda: jax.shard_map(
             body, mesh=mesh,
             in_specs=(in_specs, P(), ov_specs, P(axis)),
             out_specs=(P(axis), P(axis), P(axis))))
@@ -305,11 +327,9 @@ def sharded_range_query(mesh: Mesh, sd_arrays: dict, lo: jnp.ndarray,
     its own key range — O(log n_shard + max_hits) per query per shard — then
     writes its run into the global answer at the offset given by the
     exclusive prefix of per-shard counts (shard ranges are disjoint and
-    ordered, so shard-order concatenation IS key order).  One psum_scatter
+    ordered, so shard-order concatenation IS key order).  `_owner_scatter`
     assembles and returns each device's query slice.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[axis]
     bounds = sd_arrays["boundaries"]
     in_specs = ({k: P(axis) for k in sd_arrays if k != "boundaries"}
@@ -325,7 +345,8 @@ def sharded_range_query(mesh: Mesh, sd_arrays: dict, lo: jnp.ndarray,
         slo = jnp.maximum(lo_all, bnd[r])
         shi = jnp.maximum(jnp.minimum(hi_all, bnd[r + 1]), slo)
         start = jnp.searchsorted(pk, slo, side="left")
-        cnt = jnp.searchsorted(pk, shi, side="left") - start     # [Q]
+        cnt = (jnp.searchsorted(pk, shi, side="left")
+               - start).astype(jnp.int32)                        # [Q]
         # exclusive prefix of counts over earlier shards = this run's offset
         cnt_all = jax.lax.all_gather(cnt, axis)                  # [R, Q]
         before = jnp.sum(
@@ -334,11 +355,8 @@ def sharded_range_query(mesh: Mesh, sd_arrays: dict, lo: jnp.ndarray,
         rel = posn - before[:, None]                             # [Q, H]
         mine = (rel >= 0) & (rel < cnt[:, None])
         g = jnp.clip(start[:, None] + rel, 0, pk.shape[0] - 1)
-        # additive assembly: exactly one shard owns each (query, position)
-        ks = jnp.where(mine, pk[g], 0.0)
-        vs = jnp.where(mine, pv[g], 0)
-        ks = jax.lax.psum_scatter(ks, axis, scatter_dimension=0, tiled=True)
-        vs = jax.lax.psum_scatter(vs, axis, scatter_dimension=0, tiled=True)
+        # exactly one shard owns each (query, position)
+        (ks, vs), _ = _owner_scatter(mine, (pk[g], pv[g]), axis, n_shards)
         total = jax.lax.psum_scatter(cnt, axis, scatter_dimension=0,
                                      tiled=True)                 # [Q/R]
         filled = posn < jnp.minimum(total, max_hits)[:, None]
@@ -348,7 +366,7 @@ def sharded_range_query(mesh: Mesh, sd_arrays: dict, lo: jnp.ndarray,
 
     fn = _cached_collective(
         (mesh, axis, "range", max_hits, tuple(sorted(sd_arrays))),
-        lambda: shard_map(
+        lambda: jax.shard_map(
             body, mesh=mesh,
             in_specs=(in_specs, P(), P(axis), P(axis)),
             out_specs=(P(axis, None), P(axis, None), P(axis))))

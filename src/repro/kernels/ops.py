@@ -1,11 +1,14 @@
 """jit'd public wrapper for the DILI search kernel.
 
 Dispatch policy:
-  * tables fit the VMEM budget -> Pallas kernel (interpret=True on CPU,
-    compiled on real TPU), with an XLA fallback pass for lanes flagged
-    needs_fallback (dense leaves / depth overflow);
+  * tables fit the VMEM budget -> Pallas kernel (compiled on a TPU,
+    interpreted elsewhere), with an XLA fallback pass for lanes flagged
+    needs_fallback (depth overflow);
   * otherwise -> the pure-XLA batched path (core/search.py), which keeps
     tables in HBM and lets XLA schedule the gathers.
+
+A caller that passes a `routes` dict gets the route of each batch
+counted into it, so the size-based dispatch is never invisible.
 
 Keys are f32 on this path; the snapshot must have been built under
 ``placement_dtype(np.float32)`` so construction and kernel arithmetic agree
@@ -57,13 +60,23 @@ def table_bytes(arrs: dict) -> int:
                for k, v in arrs.items() if hasattr(v, "dtype"))
 
 
-def dili_search(arrs: dict, queries: jnp.ndarray, interpret: bool = True,
-                vmem_budget: int | None = None):
+#: the three routes a batch can take
+ROUTE_KERNEL, ROUTE_KERNEL_RECHECK, ROUTE_XLA = (
+    "kernel", "kernel+xla_recheck", "xla")
+
+
+def dili_search(arrs: dict, queries: jnp.ndarray,
+                interpret: bool | None = None,
+                vmem_budget: int | None = None,
+                routes: dict | None = None):
     """Batched lookup via the Pallas kernel with XLA fallback lanes.
 
     `vmem_budget` overrides the module-level `VMEM_BUDGET_BYTES` dispatch
     ceiling (the `IndexConfig.vmem_budget_bytes` knob of the api facade);
-    tables above it take the pure-XLA path outright.
+    tables above it take the pure-XLA path outright.  `interpret=None`
+    follows the backend (`dili_search.resolve_interpret`).  When `routes`
+    is given, the route this batch took is counted into it: the kernel
+    alone, the kernel plus an XLA recheck of flagged lanes, or XLA only.
     """
     max_depth = int(arrs["max_depth"])
     nq = queries.shape[0]
@@ -76,20 +89,26 @@ def dili_search(arrs: dict, queries: jnp.ndarray, interpret: bool = True,
             arrs["a"], arrs["b"], arrs["base"], arrs["fo"], arrs["dense"],
             arrs["tag"], arrs["key"], arrs["val"], arrs["root"], qp,
             max_depth=max_depth, interpret=interpret)
-        if bool(jnp.any(fb)):
-            # rare path: dense leaves / overflow — recheck those lanes in XLA
-            # (search_batch handles the dense exit itself, so the snapshot's
-            # exact depth is the right trip count here too)
+        recheck = bool(jnp.any(fb))
+        _count(routes, ROUTE_KERNEL_RECHECK if recheck else ROUTE_KERNEL)
+        if recheck:
+            # rare path: depth overflow — recheck those lanes in XLA
             idx = _as_search_idx(arrs)
             v2, f2 = core_search.search_batch(idx, qp, max_depth=max_depth)
             out = jnp.where(fb, v2, out)
             found = jnp.where(fb, f2, found)
         return out[:nq], found[:nq]
 
+    _count(routes, ROUTE_XLA)
     idx = _as_search_idx(arrs)
     v, f = core_search.search_batch(idx, qp, max_depth=max_depth,
                                     early_exit=True)
     return v[:nq], f[:nq]
+
+
+def _count(routes: dict | None, route: str) -> None:
+    if routes is not None:
+        routes[route] = routes.get(route, 0) + 1
 
 
 def _as_search_idx(arrs: dict) -> dict:

@@ -39,6 +39,8 @@ def main():
     ap.add_argument("--frontend-threads", type=int, default=4,
                     help="concurrent session-admission threads")
     args = ap.parse_args()
+    # the session table's index keys are f64; the model uses explicit dtypes
+    jax.config.update("jax_enable_x64", True)
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -99,12 +99,11 @@ def pipeline_forward(cfg: ModelConfig, mesh: Mesh, params, tokens,
             "pod")
         return buf
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P("pod"), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     out = fn(staged, mb)                    # [n_micro, b/m, s, d]
     x = out.reshape(b, s, -1)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)

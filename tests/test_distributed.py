@@ -195,7 +195,7 @@ def test_psum_int8_compression_collective():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.parallel.compression import psum_int8
         mesh = jax.make_mesh((8,), ("data",))
         def f(x):

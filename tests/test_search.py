@@ -189,7 +189,9 @@ def test_resolve_max_depth_rejects_tracers(snap):
 
 def test_fused_overlay_single_dispatch(snap):
     """search_with_overlay is ONE jitted computation: its jaxpr top level is
-    a single pjit call (traversal + overlay resolution fused)."""
+    a single jitted call (traversal + overlay resolution fused).  The call
+    is recognised by its nested `jaxpr` parameter, not by the primitive's
+    name, which differs across jax releases."""
     from repro.online.overlay import TombstoneOverlay, overlay_device_arrays
     keys, d, f, idx = snap
     ova = overlay_device_arrays(
@@ -197,7 +199,9 @@ def test_fused_overlay_single_dispatch(snap):
     q = jnp.asarray(keys[:8])
     jaxpr = jax.make_jaxpr(
         lambda q: S.search_with_overlay(idx, ova, q, f.max_depth))(q)
-    assert [e.primitive.name for e in jaxpr.jaxpr.eqns] == ["pjit"]
+    eqns = jaxpr.jaxpr.eqns
+    assert len(eqns) == 1 and "jaxpr" in eqns[0].params, \
+        [e.primitive.name for e in eqns]
     v, fnd = S.search_with_overlay(idx, ova, q)
     assert bool(np.asarray(fnd).all())
     assert int(np.asarray(v)[3]) == 42
