@@ -372,11 +372,19 @@ def _truncate_windows(ks, vs, cnt, max_hits: int):
     return ks, vs, cnt
 
 
-def _overlay_exact_range(entries, lo, hi, max_hits: int, device_range):
+def _upload(tel: Telemetry, dtype, *xs):
+    """Host arrays to the device, inside an `engine.upload` span."""
+    with tel.span("engine.upload"):
+        return tuple(jnp.asarray(x, dtype) for x in xs)
+
+
+def _overlay_exact_range(entries, lo, hi, max_hits: int, device_range,
+                         tel: Telemetry):
     """The one overlay-exact range recipe every engine shares: size the
     device fetch with tombstone headroom, bisect on the device via
-    `device_range(lo, hi, fetch)`, then either truncate (no pending writes)
-    or merge each query's overlay slice host-side."""
+    `device_range(lo, hi, fetch)` (which uploads and launches, and may
+    return more rows than queries), then either truncate (no pending
+    writes) or merge each query's overlay slice host-side."""
     ov_k, ov_v, ov_t = entries
     fetch = max_hits + _tombstone_headroom(ov_k, ov_t, lo, hi)
     if fetch > max_hits:
@@ -385,8 +393,9 @@ def _overlay_exact_range(entries, lo, hi, max_hits: int, device_range):
         # extra rows are clipped by the truncate/merge step below, so the
         # result is identical
         fetch = max_hits + (1 << (fetch - max_hits - 1).bit_length())
-    ks, vs, cnt = device_range(lo, hi, fetch)
-    ks, vs, cnt = np.asarray(ks), np.asarray(vs), np.asarray(cnt)
+    out = device_range(lo, hi, fetch)
+    with tel.fetch("result"):
+        ks, vs, cnt = (np.asarray(x)[:len(lo)] for x in out)
     if len(ov_k) == 0:
         return _truncate_windows(ks, vs, cnt, max_hits)
     return _merge_range_windows(ks, vs, cnt, lo, hi, ov_k, ov_v, ov_t,
@@ -423,14 +432,17 @@ class LocalEngine(EngineTelemetryBase):
         return self.oi.lookup(queries)
 
     def range(self, lo, hi, max_hits):
-        dt = self.oi.store.dtype
-        # pending entries captured BEFORE the snapshot is read inside the
-        # lambda: exact across a concurrent background publish
-        return _overlay_exact_range(
-            self.oi.pending_entries(), lo, hi, max_hits,
-            lambda lo_, hi_, fetch: S.range_query_batch(
-                self.oi.store.idx, jnp.asarray(lo_, dt),
-                jnp.asarray(hi_, dt), max_hits=fetch))
+        tel = self.telemetry
+
+        def device_range(lo_, hi_, fetch):
+            lo_d, hi_d = _upload(tel, self.oi.store.dtype, lo_, hi_)
+            with tel.span("engine.launch"):
+                return S.range_query_batch(self.oi.store.idx, lo_d, hi_d,
+                                           max_hits=fetch)
+        # pending entries captured BEFORE the snapshot is read inside
+        # device_range: exact across a concurrent background publish
+        return _overlay_exact_range(self.oi.pending_entries(), lo, hi,
+                                    max_hits, device_range, tel)
 
     def get(self, key: float):
         return self.oi.get(key)
@@ -688,30 +700,41 @@ class PallasEngine(EngineTelemetryBase):
     # -- reads --------------------------------------------------------------
 
     def lookup(self, queries):
-        q32 = jnp.asarray(np.asarray(queries, np.float64), jnp.float32)
+        tel = self.telemetry
+        rebuild = bool(self.overlay.count) and self._ov_mirror is None
+        with tel.span("engine.upload", overlay=int(rebuild)):
+            q32 = jnp.asarray(np.asarray(queries, np.float64), jnp.float32)
+            if rebuild:
+                self._ov_mirror = overlay_device_arrays(self.overlay,
+                                                        jnp.float32)
         v, f = self._K.dili_search(self.arrs, q32,
                                    interpret=self.cfg.interpret,
                                    vmem_budget=self.cfg.vmem_budget_bytes,
-                                   routes=self.routes)
-        v, f, patched = _pair_table_recheck(self.snap.arrays["pair_key"],
-                                            self.snap.arrays["pair_val"],
-                                            q32, v, f)
-        self.recheck_patched_lanes += int(patched)
+                                   routes=self.routes, telemetry=tel)
+        with tel.span("engine.launch"):
+            v, f, patched = _pair_table_recheck(
+                self.snap.arrays["pair_key"], self.snap.arrays["pair_val"],
+                q32, v, f)
+        with tel.fetch("recheck"):
+            self.recheck_patched_lanes += int(patched)
         if self.overlay.count:
-            if self._ov_mirror is None:
-                self._ov_mirror = overlay_device_arrays(self.overlay,
-                                                        jnp.float32)
-            v, f = S.resolve_overlay(self._ov_mirror, q32, v, f)
-        return np.asarray(v, np.int64), np.asarray(f, bool)
+            with tel.span("engine.launch"):
+                v, f = S.resolve_overlay(self._ov_mirror, q32, v, f)
+        with tel.fetch("result"):
+            return np.asarray(v, np.int64), np.asarray(f, bool)
 
     def range(self, lo, hi, max_hits):
+        tel = self.telemetry
         lo32 = np.asarray(lo, np.float64).astype(np.float32)
         hi32 = np.asarray(hi, np.float64).astype(np.float32)
-        return _overlay_exact_range(
-            self.overlay.entries(), lo32, hi32, max_hits,
-            lambda lo_, hi_, fetch: S.range_query_batch(
-                self.snap, jnp.asarray(lo_, jnp.float32),
-                jnp.asarray(hi_, jnp.float32), max_hits=fetch))
+
+        def device_range(lo_, hi_, fetch):
+            lo_d, hi_d = _upload(tel, jnp.float32, lo_, hi_)
+            with tel.span("engine.launch"):
+                return S.range_query_batch(self.snap, lo_d, hi_d,
+                                           max_hits=fetch)
+        return _overlay_exact_range(self.overlay.entries(), lo32, hi32,
+                                    max_hits, device_range, tel)
 
     def get(self, key: float):
         k = float(np.float32(key))
@@ -751,15 +774,18 @@ class PallasEngine(EngineTelemetryBase):
     def upsert(self, keys, vals):
         # overlay reads resolve in int64, but a merge folds these into the
         # int32 kernel tables — enforce the width before accepting the write
-        vals = self._check_vals_i32(np.atleast_1d(np.asarray(vals)))
-        self.overlay = self.overlay.upsert_batch(self._quantize_keys(keys),
-                                                 vals)
-        self._ov_mirror = None
+        with self.telemetry.span("engine.write"):
+            vals = self._check_vals_i32(np.atleast_1d(np.asarray(vals)))
+            self.overlay = self.overlay.upsert_batch(
+                self._quantize_keys(keys), vals)
+            self._ov_mirror = None
         self._note_writes(len(np.atleast_1d(keys)))
 
     def delete(self, keys):
-        self.overlay = self.overlay.delete_batch(self._quantize_keys(keys))
-        self._ov_mirror = None
+        with self.telemetry.span("engine.write"):
+            self.overlay = self.overlay.delete_batch(
+                self._quantize_keys(keys))
+            self._ov_mirror = None
         self._note_writes(len(np.atleast_1d(keys)))
 
     def _note_writes(self, n: int):
@@ -912,43 +938,52 @@ class ShardedEngine(EngineTelemetryBase):
     # -- reads --------------------------------------------------------------
 
     def lookup(self, queries):
-        q, n = self._pad(queries)
-        qd = jnp.asarray(q, self.cfg.resolved_dtype)
-        ova = combined_overlay_arrays(self.sd, self.cfg.resolved_dtype)
-        out = sharded_lookup(self.mesh, self.arrs, qd, self.sd.max_depth,
-                             axis=self.cfg.mesh_axis,
-                             strategy=self.cfg.lookup_strategy, overlay=ova,
-                             has_dense=self.sd.has_dense)
-        if (self.cfg.lookup_strategy == "a2a"
-                and int(np.asarray(out[2]).sum()) > 0):
-            # a2a buckets are capacity-bounded; overflowed lanes come back
-            # found=False.  The facade's contract is exact results, so a
-            # skewed batch that overflows re-resolves on the (always-exact)
-            # gather path instead of silently reporting misses.
+        tel = self.telemetry
+        dt = self.cfg.resolved_dtype
+        stale = np.dtype(dt).name not in self.sd._ov_cache
+        with tel.span("engine.upload", overlay=int(stale)):
+            q, n = self._pad(queries)
+            qd = jnp.asarray(q, dt)
+            ova = combined_overlay_arrays(self.sd, dt)
+        with tel.span("engine.launch"):
             out = sharded_lookup(self.mesh, self.arrs, qd,
                                  self.sd.max_depth, axis=self.cfg.mesh_axis,
-                                 strategy="gather", overlay=ova,
-                                 has_dense=self.sd.has_dense)
+                                 strategy=self.cfg.lookup_strategy,
+                                 overlay=ova, has_dense=self.sd.has_dense)
+        if self.cfg.lookup_strategy == "a2a":
+            with tel.fetch("overflow"):
+                overflow = int(np.asarray(out[2]).sum()) > 0
+            if overflow:
+                # a2a buckets are capacity-bounded; overflowed lanes come
+                # back found=False.  The facade's contract is exact
+                # results, so a skewed batch that overflows re-resolves on
+                # the (always-exact) gather path instead of silently
+                # reporting misses.
+                with tel.span("engine.launch"):
+                    out = sharded_lookup(
+                        self.mesh, self.arrs, qd, self.sd.max_depth,
+                        axis=self.cfg.mesh_axis, strategy="gather",
+                        overlay=ova, has_dense=self.sd.has_dense)
         v, f = out[0], out[1]
-        return (np.asarray(v, np.int64)[:n], np.asarray(f, bool)[:n])
+        with tel.fetch("result"):
+            return (np.asarray(v, np.int64)[:n], np.asarray(f, bool)[:n])
 
     def range(self, lo, hi, max_hits):
+        tel = self.telemetry
         lo_p, n = self._pad(lo)
         hi_p, _ = self._pad(hi)
-        dt = self.cfg.resolved_dtype
 
         def device_range(_lo, _hi, fetch):
-            # the collective needs the shard-multiple padded batch; results
-            # are sliced back to the caller's n queries
-            ks, vs, cnt = sharded_range_query(
-                self.mesh, self.arrs, jnp.asarray(lo_p, dt),
-                jnp.asarray(hi_p, dt), max_hits=fetch,
-                axis=self.cfg.mesh_axis)
-            return (np.asarray(ks)[:n], np.asarray(vs)[:n],
-                    np.asarray(cnt)[:n])
+            # the collective needs the shard-multiple padded batch; the
+            # shared recipe slices results back to the caller's n queries
+            lo_d, hi_d = _upload(tel, self.cfg.resolved_dtype, lo_p, hi_p)
+            with tel.span("engine.launch"):
+                return sharded_range_query(self.mesh, self.arrs, lo_d, hi_d,
+                                           max_hits=fetch,
+                                           axis=self.cfg.mesh_axis)
 
         return _overlay_exact_range(self._overlay_entries(), lo_p[:n],
-                                    hi_p[:n], max_hits, device_range)
+                                    hi_p[:n], max_hits, device_range, tel)
 
     def _overlay_entries(self):
         """Combined overlay entries, globally sorted (disjoint shard
@@ -971,11 +1006,13 @@ class ShardedEngine(EngineTelemetryBase):
     # -- writes -------------------------------------------------------------
 
     def upsert(self, keys, vals):
-        sharded_upsert(self.sd, keys, vals)
+        with self.telemetry.span("engine.write"):
+            sharded_upsert(self.sd, keys, vals)
         self._note_writes(len(np.atleast_1d(keys)))
 
     def delete(self, keys):
-        sharded_delete(self.sd, keys)
+        with self.telemetry.span("engine.write"):
+            sharded_delete(self.sd, keys)
         self._note_writes(len(np.atleast_1d(keys)))
 
     def _note_writes(self, n: int):

@@ -137,16 +137,18 @@ class LearnedIndex:
     def lookup(self, queries) -> tuple[np.ndarray, np.ndarray]:
         """Batched point lookups -> (vals int64, found bool); vals only
         valid where found."""
-        q = np.atleast_1d(np.asarray(queries, np.float64))
-        if not np.isfinite(q).all():
-            # engines use +/-inf internally as padding/boundary sentinels;
-            # a non-finite query would match them (engine-dependently)
-            raise ValueError("queries must be finite")
-        n = len(q)
-        lanes = self._pad_batch(n)
-        if lanes > n:
-            q = np.concatenate([q, np.full(lanes - n, q[0])])
         tel = self._engine.telemetry
+        with tel.span("engine.prep"):
+            q = np.atleast_1d(np.asarray(queries, np.float64))
+            if not np.isfinite(q).all():
+                # engines use +/-inf internally as padding/boundary
+                # sentinels; a non-finite query would match them
+                # (engine-dependently)
+                raise ValueError("queries must be finite")
+            n = len(q)
+            lanes = self._pad_batch(n)
+            if lanes > n:
+                q = np.concatenate([q, np.full(lanes - n, q[0])])
         if tel.enabled:
             t0 = time.perf_counter()
             v, f = self._engine.lookup(q)
@@ -168,22 +170,23 @@ class LearnedIndex:
         (keys [Q,H] +inf-padded, vals [Q,H] -1-padded, counts [Q]
         saturating at `max_hits`).  Overlay-exact: pending upserts appear,
         pending deletes are hidden."""
-        lo = np.atleast_1d(np.asarray(lo, np.float64))
-        hi = np.atleast_1d(np.asarray(hi, np.float64))
-        if lo.shape != hi.shape:
-            raise ValueError(f"lo {lo.shape} vs hi {hi.shape}")
-        if not (np.isfinite(lo).all() and np.isfinite(hi).all()):
-            raise ValueError("range bounds must be finite")
-        if max_hits is None:
-            max_hits = self.config.max_hits
-        if max_hits < 1:
-            raise ValueError(f"max_hits must be >= 1, got {max_hits}")
-        n = len(lo)
-        lanes = self._pad_batch(n)
-        if lanes > n:
-            lo = np.concatenate([lo, np.full(lanes - n, lo[0])])
-            hi = np.concatenate([hi, np.full(lanes - n, hi[0])])
         tel = self._engine.telemetry
+        with tel.span("engine.prep"):
+            lo = np.atleast_1d(np.asarray(lo, np.float64))
+            hi = np.atleast_1d(np.asarray(hi, np.float64))
+            if lo.shape != hi.shape:
+                raise ValueError(f"lo {lo.shape} vs hi {hi.shape}")
+            if not (np.isfinite(lo).all() and np.isfinite(hi).all()):
+                raise ValueError("range bounds must be finite")
+            if max_hits is None:
+                max_hits = self.config.max_hits
+            if max_hits < 1:
+                raise ValueError(f"max_hits must be >= 1, got {max_hits}")
+            n = len(lo)
+            lanes = self._pad_batch(n)
+            if lanes > n:
+                lo = np.concatenate([lo, np.full(lanes - n, lo[0])])
+                hi = np.concatenate([hi, np.full(lanes - n, hi[0])])
         if tel.enabled:
             t0 = time.perf_counter()
             ks, vs, cnt = self._engine.range(lo, hi, max_hits)

@@ -8,7 +8,9 @@ Dispatch policy:
     tables in HBM and lets XLA schedule the gathers.
 
 A caller that passes a `routes` dict gets the route of each batch
-counted into it, so the size-based dispatch is never invisible.
+counted into it, so the size-based dispatch is never invisible; one that
+passes its `telemetry` gets the launches and the route read as
+`engine.launch` / `engine.fetch` spans.
 
 Keys are f32 on this path; the snapshot must have been built under
 ``placement_dtype(np.float32)`` so construction and kernel arithmetic agree
@@ -24,6 +26,7 @@ import jax.numpy as jnp
 from ..core import search as core_search
 from ..core.dili import bulk_load, placement_dtype
 from ..core.flat import FlatDILI, flatten
+from ..obs.telemetry import NULL_TELEMETRY
 from .dili_search import BLOCK_Q, dili_search_pallas
 
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
@@ -68,7 +71,7 @@ ROUTE_KERNEL, ROUTE_KERNEL_RECHECK, ROUTE_XLA = (
 def dili_search(arrs: dict, queries: jnp.ndarray,
                 interpret: bool | None = None,
                 vmem_budget: int | None = None,
-                routes: dict | None = None):
+                routes: dict | None = None, telemetry=NULL_TELEMETRY):
     """Batched lookup via the Pallas kernel with XLA fallback lanes.
 
     `vmem_budget` overrides the module-level `VMEM_BUDGET_BYTES` dispatch
@@ -78,32 +81,39 @@ def dili_search(arrs: dict, queries: jnp.ndarray,
     is given, the route this batch took is counted into it: the kernel
     alone, the kernel plus an XLA recheck of flagged lanes, or XLA only.
     """
+    tel = telemetry
     max_depth = int(arrs["max_depth"])
     nq = queries.shape[0]
     pad = (-nq) % BLOCK_Q
-    qp = jnp.pad(queries, (0, pad), constant_values=jnp.inf)
-
     budget = VMEM_BUDGET_BYTES if vmem_budget is None else vmem_budget
     if table_bytes(arrs) <= budget:
-        out, found, fb = dili_search_pallas(
-            arrs["a"], arrs["b"], arrs["base"], arrs["fo"], arrs["dense"],
-            arrs["tag"], arrs["key"], arrs["val"], arrs["root"], qp,
-            max_depth=max_depth, interpret=interpret)
-        recheck = bool(jnp.any(fb))
+        with tel.span("engine.launch"):
+            qp = jnp.pad(queries, (0, pad), constant_values=jnp.inf)
+            out, found, fb = dili_search_pallas(
+                arrs["a"], arrs["b"], arrs["base"], arrs["fo"],
+                arrs["dense"], arrs["tag"], arrs["key"], arrs["val"],
+                arrs["root"], qp, max_depth=max_depth, interpret=interpret)
+            any_fb = jnp.any(fb)
+        with tel.fetch("route"):
+            recheck = bool(any_fb)
         _count(routes, ROUTE_KERNEL_RECHECK if recheck else ROUTE_KERNEL)
-        if recheck:
-            # rare path: depth overflow — recheck those lanes in XLA
-            idx = _as_search_idx(arrs)
-            v2, f2 = core_search.search_batch(idx, qp, max_depth=max_depth)
-            out = jnp.where(fb, v2, out)
-            found = jnp.where(fb, f2, found)
-        return out[:nq], found[:nq]
+        with tel.span("engine.launch"):
+            if recheck:
+                # rare path: depth overflow — recheck those lanes in XLA
+                idx = _as_search_idx(arrs)
+                v2, f2 = core_search.search_batch(idx, qp,
+                                                  max_depth=max_depth)
+                out = jnp.where(fb, v2, out)
+                found = jnp.where(fb, f2, found)
+            return out[:nq], found[:nq]
 
     _count(routes, ROUTE_XLA)
-    idx = _as_search_idx(arrs)
-    v, f = core_search.search_batch(idx, qp, max_depth=max_depth,
-                                    early_exit=True)
-    return v[:nq], f[:nq]
+    with tel.span("engine.launch"):
+        qp = jnp.pad(queries, (0, pad), constant_values=jnp.inf)
+        idx = _as_search_idx(arrs)
+        v, f = core_search.search_batch(idx, qp, max_depth=max_depth,
+                                        early_exit=True)
+        return v[:nq], f[:nq]
 
 
 def _count(routes: dict | None, route: str) -> None:

@@ -13,8 +13,8 @@ from .metrics import (LatencyHistogram, MetricsRegistry, PERCENTILES,
 from .telemetry import NULL_TELEMETRY, OPS, SCHEMA_VERSION, Telemetry
 from .trace_export import (TRACE_SCHEMA_VERSION, TraceBuffer,
                            current_trace_ids, mint_trace_id, trace_context)
-from .tracing import (MERGE_SPANS, RECOVERY_SPANS, SERVE_SPANS, Span,
-                      SpanRecorder)
+from .tracing import (ENGINE_SPANS, GC_SPAN, MERGE_SPANS, RECOVERY_SPANS,
+                      SERVE_SPANS, Span, SpanRecorder)
 from .inspect import INSPECT_SCHEMA_VERSION, build_inspect
 from . import watchdog
 
@@ -23,7 +23,8 @@ __all__ = [
     "NULL_TELEMETRY", "OPS", "SCHEMA_VERSION", "Telemetry",
     "TRACE_SCHEMA_VERSION", "TraceBuffer", "current_trace_ids",
     "mint_trace_id", "trace_context",
-    "MERGE_SPANS", "RECOVERY_SPANS", "SERVE_SPANS", "Span", "SpanRecorder",
+    "ENGINE_SPANS", "GC_SPAN", "MERGE_SPANS", "RECOVERY_SPANS",
+    "SERVE_SPANS", "Span", "SpanRecorder",
     "INSPECT_SCHEMA_VERSION", "build_inspect",
     "watchdog",
 ]

@@ -3,28 +3,31 @@ section 13) — one `MetricsRegistry` + one `SpanRecorder` + a retrace
 watchdog window, behind a single `enabled` flag.
 
 Cost contract: with `enabled=False` (the default) the read/write hot path
-pays exactly one attribute check plus one integer op-count increment per
-facade call — the op count must keep flowing even when latency capture is
-off, because `retraces_per_1k_ops` (the PR-4 regression number) is
-meaningful either way and the watchdog's trace counters are fed by jax's
-own compile hooks, not by the hot path.  With `enabled=True` each facade
-call additionally pays one perf_counter pair and one histogram bucket
-increment (<= 3% on the ycsb_c point-lookup loop, pinned by a test).
+pays one attribute check per span site plus one integer op-count
+increment per facade call — the op count must keep flowing even when
+latency capture is off, because `retraces_per_1k_ops` (the PR-4
+regression number) is meaningful either way and the watchdog's trace
+counters are fed by jax's own compile hooks, not by the hot path.  With
+`enabled=True` each facade call additionally pays one histogram bucket
+increment and, per `engine.*` span, a perf_counter pair and a profiler
+annotation (<= 3% on the ycsb_c point-lookup loop, pinned by a test).
 
 Snapshot schema (`snapshot()`) is identical across engines — fixed op
-set, fixed merge-span taxonomy, fixed retrace keys — pinned by the
-engine-equivalence suite so downstream consumers (BENCH_PR2.json, the
-serving front-end to come) can rely on it.
+set, fixed merge- and engine-span taxonomies, fixed retrace keys —
+pinned by the engine-equivalence suite so downstream consumers
+(BENCH_PR2.json, the serving front-end to come) can rely on it.
 """
 
 from __future__ import annotations
 
+import gc
 import time
 
 from . import watchdog
 from .metrics import MetricsRegistry
 from .trace_export import TraceBuffer
-from .tracing import MERGE_SPANS, RECOVERY_SPANS, SpanRecorder
+from .tracing import (ENGINE_SPANS, GC_SPAN, MERGE_SPANS, RECOVERY_SPANS,
+                      SpanRecorder, trace_annotation)
 
 # the facade op set: every engine serves exactly these through
 # `repro.api.LearnedIndex`, so per-op histograms share one name space
@@ -44,6 +47,36 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _GcSpans:
+    """`gc.callbacks` hook: one `host.gc` span per collection.  A
+    collection's start and stop run on the thread that triggered it, so
+    the profiler annotation is entered in one callback and left in the
+    next."""
+
+    def __init__(self, spans):
+        self.spans = spans
+        self._t0 = None
+        self._note = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._note = trace_annotation(GC_SPAN,
+                                          generation=info["generation"])
+            if self._note is not None:
+                self._note.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        t0, note = self._t0, self._note
+        if t0 is None:              # hooked in mid-collection
+            return
+        t1 = time.perf_counter()
+        if note is not None:
+            note.__exit__(None, None, None)
+        self._t0 = self._note = None
+        self.spans.record(GC_SPAN, t1 - t0, t0=t0,
+                          generation=info["generation"])
+
+
 class Telemetry:
     """Metrics + spans + retrace window for ONE index instance."""
 
@@ -55,6 +88,9 @@ class Telemetry:
                                      "maint.reclusters",
                                      "recovery.count",
                                      "recovery.replayed_records",
+                                     # blocking device-to-host reads, one
+                                     # per `engine.fetch` span
+                                     "engine.host_syncs",
                                      # structured warning counters
                                      # (MetricsRegistry.warn): declared so
                                      # the counter key tree is identical on
@@ -66,7 +102,8 @@ class Telemetry:
                                    "inspect.dirty_rows",
                                    "inspect.total_rows",
                                    "inspect.dirty_fraction")
-        self.spans = SpanRecorder(declare=MERGE_SPANS + RECOVERY_SPANS)
+        self.spans = SpanRecorder(
+            declare=MERGE_SPANS + RECOVERY_SPANS + ENGINE_SPANS)
         self.trace = TraceBuffer()
         self.ops_total = 0
         # watchdog window: the build mark anchors "traces since build";
@@ -74,6 +111,8 @@ class Telemetry:
         self._build_mark = watchdog.TraceMark.now()
         self._warm_mark: watchdog.TraceMark | None = None
         self._ops_at_warm = 0
+        self._gc_hook = None
+        self._gc_watchers = 0
 
     # -- hot path -------------------------------------------------------------
 
@@ -87,11 +126,11 @@ class Telemetry:
         self.ops_total += n
         self.metrics.observe(f"op.{op}", dur_s)
 
-    # -- merge pipeline -------------------------------------------------------
+    # -- spans ----------------------------------------------------------------
 
     def span(self, name: str, **attrs):
-        """Context manager timing one pipeline stage; no-op when
-        disabled (merge-path only — never on the per-op hot path)."""
+        """Context manager timing one stage (and annotating it on a
+        `jax.profiler` trace); no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
         return self.spans.span(name, **attrs)
@@ -99,6 +138,37 @@ class Telemetry:
     def record_span(self, name: str, dur_s: float, **attrs) -> None:
         if self.enabled:
             self.spans.record(name, dur_s, **attrs)
+
+    def fetch(self, what: str):
+        """`engine.fetch` span around one blocking device-to-host read
+        (`what`: route, recheck, overflow or result), counted into
+        `engine.host_syncs`; no-op when disabled."""
+        if not self.enabled:
+            return _NULL_SPAN
+        self.metrics.count("engine.host_syncs")
+        return self.spans.span("engine.fetch", what=what)
+
+    # -- garbage collections --------------------------------------------------
+
+    def watch_gc(self) -> None:
+        """Record one `host.gc` span (and profiler annotation) per Python
+        garbage collection until the matching `unwatch_gc`.  Nests: the
+        hook is installed once however many callers watch."""
+        if not self.enabled:
+            return
+        self._gc_watchers += 1
+        if self._gc_hook is None:
+            self._gc_hook = _GcSpans(self.spans)
+            gc.callbacks.append(self._gc_hook)
+
+    def unwatch_gc(self) -> None:
+        if self._gc_hook is None:
+            return
+        self._gc_watchers -= 1
+        if self._gc_watchers <= 0:
+            gc.callbacks.remove(self._gc_hook)
+            self._gc_hook = None
+            self._gc_watchers = 0
 
     # -- causal tracing -------------------------------------------------------
 
@@ -187,10 +257,3 @@ class Telemetry:
 #: shared disabled instance for call sites that accept an optional
 #: telemetry (never enable this one — make your own)
 NULL_TELEMETRY = Telemetry(enabled=False)
-
-
-def timed(fn, *args, **kw):
-    """(result, dur_s) convenience for one-off stage timing."""
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    return out, time.perf_counter() - t0

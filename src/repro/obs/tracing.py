@@ -30,14 +30,18 @@ load (checkpoint walk + npz read), replay (WAL tail through the fold
 path), publish (fresh base checkpoint + WAL re-arm).  Recovery spans are
 recorded unconditionally — bypassing the telemetry `enabled` gate —
 because recovery is rare and always worth seeing.
+
+A context span (`SpanRecorder.span`, reached through an enabled
+`Telemetry.span`) also opens a `jax.profiler.TraceAnnotation` of the same
+name, so a `jax.profiler` trace shows it on the host plane, on the clock
+the device's events use.  Retroactive spans (`record`) are not annotated.
 """
 
 from __future__ import annotations
 
 import time
 from collections import deque
-from contextlib import contextmanager
-from dataclasses import dataclass, field
+from typing import NamedTuple
 
 from .metrics import latency_summary
 
@@ -57,15 +61,79 @@ RECOVERY_SPANS = ("recovery.load", "recovery.replay", "recovery.publish")
 #                      admission-queue delay component of e2e latency)
 #   serve.exec       — one coalesced facade batch, dispatch -> results
 #                      sliced back to clients (attr `op`)
-SERVE_SPANS = ("serve.queue_wait", "serve.exec")
+#   serve.wait_for_work — the worker blocked on an empty queue
+#   serve.dwell      — the worker's bounded wait for a batch to fill
+#   serve.dispatch   — the worker's turn for one batch: serve.exec and the
+#                      per-request accounting after it, so that the worker
+#                      is always inside a wait or a dispatch
+#   serve.complete   — journal, result slicing and waking the clients
+#                      (inside serve.exec)
+SERVE_SPANS = ("serve.queue_wait", "serve.exec", "serve.wait_for_work",
+               "serve.dwell", "serve.dispatch", "serve.complete")
+
+# One Python garbage collection (attr `generation`), recorded from
+# `gc.callbacks` while a batcher with enabled telemetry is attached
+# (`Telemetry.watch_gc`): every thread stops for it.
+GC_SPAN = "host.gc"
+
+# Engine taxonomy: the stages of one facade call, declared on every engine
+# so the key tree is identical across engines.
+#
+#   engine.prep   — the facade's asarray, finiteness check and pow2 pad
+#   engine.upload — host-to-device copy of the queries, plus the overlay
+#                   mirror rebuild (attr `overlay=1` when it ran)
+#   engine.launch — calling a jitted executable or the kernel, until
+#                   control returns (dispatch is asynchronous)
+#   engine.fetch  — one blocking device-to-host read (attr `what`: route,
+#                   recheck, overflow or result); each one also counts
+#                   `engine.host_syncs`
+#   engine.write  — the host-side overlay apply of an upsert or delete
+ENGINE_SPANS = ("engine.prep", "engine.upload", "engine.launch",
+                "engine.fetch", "engine.write")
+
+_annotation = None
 
 
-@dataclass(frozen=True)
-class Span:
+def trace_annotation(name: str, **attrs):
+    """A `jax.profiler.TraceAnnotation` to enter, or None while no
+    profiler trace is being taken (jax is imported on first use, so the
+    module stays importable without it)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name, **attrs) if _annotation.is_enabled() else None
+
+
+class Span(NamedTuple):
     name: str
     t0: float                  # perf_counter timestamp at stage start
     dur_s: float
-    attrs: dict = field(default_factory=dict)
+    attrs: dict
+
+
+class _OpenSpan:
+    """One context span in flight: timed, recorded on exit, and annotated
+    on the profiler trace when one is being taken."""
+
+    __slots__ = ("rec", "name", "attrs", "note", "t0")
+
+    def __init__(self, rec: "SpanRecorder", name: str, attrs: dict):
+        self.rec, self.name, self.attrs = rec, name, attrs
+
+    def __enter__(self):
+        self.note = trace_annotation(self.name, **self.attrs)
+        if self.note is not None:
+            self.note.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t0 = self.t0
+        self.rec._record(self.name, time.perf_counter() - t0, t0, self.attrs)
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        return False
 
 
 class SpanRecorder:
@@ -73,7 +141,7 @@ class SpanRecorder:
 
     def __init__(self, maxlen: int = 2048,
                  declare: tuple[str, ...] = MERGE_SPANS + RECOVERY_SPANS):
-        self.ring: deque[Span] = deque(maxlen=maxlen)
+        self.ring: deque[tuple] = deque(maxlen=maxlen)
         self._durations: dict[str, list[float]] = {n: [] for n in declare}
         # optional causal-trace tap: when set (see Telemetry.start_trace)
         # every recorded span is also forwarded as
@@ -84,18 +152,21 @@ class SpanRecorder:
                **attrs) -> None:
         if t0 is None:
             t0 = time.perf_counter() - dur_s
-        self.ring.append(Span(name, t0, dur_s, attrs))
-        self._durations.setdefault(name, []).append(dur_s)
+        self._record(name, dur_s, t0, attrs)
+
+    def _record(self, name: str, dur_s: float, t0: float,
+                attrs: dict) -> None:
+        self.ring.append((name, t0, dur_s, attrs))   # a Span's fields
+        durs = self._durations.get(name)
+        if durs is None:
+            durs = self._durations.setdefault(name, [])
+        durs.append(dur_s)
         if self.sink is not None:
             self.sink(name, t0, dur_s, attrs)
 
-    @contextmanager
-    def span(self, name: str, **attrs):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, time.perf_counter() - t0, t0=t0, **attrs)
+    def span(self, name: str, **attrs) -> _OpenSpan:
+        """Context manager timing (and annotating) one stage."""
+        return _OpenSpan(self, name, attrs)
 
     def declare(self, *names: str) -> None:
         """Add span names to the exported taxonomy (zero-count until
@@ -106,7 +177,8 @@ class SpanRecorder:
             self._durations.setdefault(name, [])
 
     def spans(self, name: str | None = None) -> list[Span]:
-        return [s for s in self.ring if name is None or s.name == name]
+        return [Span(*s) for s in list(self.ring)
+                if name is None or s[0] == name]
 
     def count(self, name: str) -> int:
         return len(self._durations.get(name, ()))

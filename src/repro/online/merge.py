@@ -160,16 +160,18 @@ class OnlineIndex:
         self.upsert_batch([key], [val])
 
     def upsert_batch(self, keys, vals) -> None:
-        self.overlay = self.overlay.upsert_batch(keys, vals)
-        self._unlocated_keys.extend(np.atleast_1d(keys).tolist())
+        with self.tel.span("engine.write"):
+            self.overlay = self.overlay.upsert_batch(keys, vals)
+            self._unlocated_keys.extend(np.atleast_1d(keys).tolist())
         self._note_writes(len(np.atleast_1d(keys)))
 
     def delete(self, key: float) -> None:
         self.delete_batch([key])
 
     def delete_batch(self, keys) -> None:
-        self.overlay = self.overlay.delete_batch(keys)
-        self._unlocated_keys.extend(np.atleast_1d(keys).tolist())
+        with self.tel.span("engine.write"):
+            self.overlay = self.overlay.delete_batch(keys)
+            self._unlocated_keys.extend(np.atleast_1d(keys).tolist())
         self._note_writes(len(np.atleast_1d(keys)))
 
     def _note_writes(self, n: int) -> None:
@@ -434,6 +436,12 @@ class OnlineIndex:
             return ov.entries()
         return mg.merged_with(ov).entries()
 
+    def _overlay_stale(self) -> bool:
+        """Will the next `_overlay_arrays` rebuild (upload) the mirror?"""
+        c = self._ov_cache
+        return c is None or c[0] is not self.overlay or c[1] is not \
+            self._merging
+
     def _overlay_arrays(self) -> dict:
         ov, mg = self.overlay, self._merging
         c = self._ov_cache
@@ -450,14 +458,19 @@ class OnlineIndex:
         manual threading), query buffer donated (it is freshly uploaded
         here, so the read path never copies it back)."""
         from ..core import search as S
-        # overlay BEFORE snapshot (see pending_entries for the ordering)
-        ova = self._overlay_arrays()
-        idx = self.store.idx
-        q = jnp.asarray(queries, self.store.dtype)
-        v, f = S.search_with_overlay(idx, ova,
-                                     q, early_exit=self.early_exit,
-                                     donate_queries=q is not queries)
-        return np.asarray(v), np.asarray(f)
+        tel = self.tel
+        with tel.span("engine.upload",
+                      overlay=int(tel.enabled and self._overlay_stale())):
+            # overlay BEFORE snapshot (see pending_entries for the ordering)
+            ova = self._overlay_arrays()
+            idx = self.store.idx
+            q = jnp.asarray(queries, self.store.dtype)
+        with tel.span("engine.launch"):
+            v, f = S.search_with_overlay(idx, ova,
+                                         q, early_exit=self.early_exit,
+                                         donate_queries=q is not queries)
+        with tel.fetch("result"):
+            return np.asarray(v), np.asarray(f)
 
     def get(self, key: float) -> int | None:
         """Host-side exact point read (overlay state wins).  Resolves

@@ -30,18 +30,22 @@ Why this shape:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass
 
 import numpy as np
 
+from ..obs.telemetry import NULL_TELEMETRY
 from ..obs.trace_export import mint_trace_id, trace_context
-from ..obs.tracing import SERVE_SPANS  # noqa: F401  (re-export convenience)
+from ..obs.tracing import GC_SPAN, SERVE_SPANS
 from ..workloads.generator import OpBatch
 
 #: ops a request may carry — the facade's batched entry points
 SERVE_OPS = ("lookup", "range", "upsert", "delete")
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class RejectedError(RuntimeError):
@@ -223,14 +227,15 @@ class RequestBatcher:
         self.index = index
         self.cfg = config or ServeConfig()
         self.sizer = AdaptiveBatchSizer(self.cfg)
-        self.tel = telemetry if telemetry is not None \
-            else getattr(index, "telemetry", None)
-        if self.tel is not None:
+        self.tel = (telemetry if telemetry is not None
+                    else getattr(index, "telemetry", None)) or NULL_TELEMETRY
+        if self.tel is not NULL_TELEMETRY:
             # serve taxonomy lives in the SAME per-index telemetry bundle,
             # so `LearnedIndex.metrics()` exports it alongside merge spans
-            self.tel.spans.declare(*SERVE_SPANS)
+            self.tel.spans.declare(*SERVE_SPANS, GC_SPAN)
             self.tel.metrics.declare_histogram(
-                *(f"serve.e2e.{op}" for op in SERVE_OPS), "serve.batch.ops")
+                *(f"serve.e2e.{op}" for op in SERVE_OPS))
+            self.tel.watch_gc()         # no-op unless enabled
         self.journal: list[OpBatch] | None = [] if journal else None
         self._lock = threading.Lock()
         self._nonempty = threading.Condition(self._lock)
@@ -247,7 +252,12 @@ class RequestBatcher:
         self.n_completed = 0
         self.n_failed = 0
         self.n_batches = 0
-        self.batch_ops: list[int] = []      # per dispatched batch
+        self.batch_ops_total = 0            # lanes over all batches
+        # the worker's idle seconds (`serve.wait_for_work` + `serve.dwell`,
+        # timed while telemetry is enabled): finished waits, and the start
+        # of the wait in progress; written and read under `_lock`
+        self.worker_idle_s = 0.0
+        self.worker_idle_since: float | None = None
         self._worker = threading.Thread(target=self._run,
                                         name="serve-batcher", daemon=True)
         self._worker.start()
@@ -295,10 +305,17 @@ class RequestBatcher:
             self._stop = True
             self._nonempty.notify_all()
         self._worker.join(timeout=60.0)
+        self.tel.unwatch_gc()
 
     def stats(self) -> dict:
-        """Racy-but-safe counter sample (plain int reads)."""
-        n_b = self.n_batches
+        """Racy-but-safe counter sample (plain int reads); the batch and
+        idle totals are read together under the lock.  `worker_idle_s`
+        counts finished waits only and `worker_idle_since` is the start of
+        the wait in progress (None while the worker is busy), so a caller
+        can cut the idle time at any instant."""
+        with self._lock:
+            n_b, lanes = self.n_batches, self.batch_ops_total
+            idle_s, since = self.worker_idle_s, self.worker_idle_since
         return dict(accepted_ops=self.n_accepted, shed_ops=self.n_shed,
                     completed_ops=self.n_completed,
                     failed_ops=self.n_failed,
@@ -306,26 +323,47 @@ class RequestBatcher:
                     / max(self.n_accepted + self.n_shed, 1),
                     n_batches=n_b,
                     queue_depth_ops=self._pending_ops,
-                    batch_ops_mean=(sum(self.batch_ops[:n_b]) / n_b
-                                    if n_b else 0.0),
+                    batch_ops_mean=lanes / n_b if n_b else 0.0,
                     batch_target_ops=self.sizer.target,
+                    worker_idle_s=idle_s, worker_idle_since=since,
                     journal_batches=(len(self.journal)
                                      if self.journal is not None else 0))
 
     # -- worker side ---------------------------------------------------------
 
+    def _waiting(self, name: str):
+        """Span over one worker wait (`serve.wait_for_work` or
+        `serve.dwell`), added to `worker_idle_s`; entered and left under
+        `_lock`.  A no-op when telemetry is disabled."""
+        if not self.tel.enabled:
+            return _NO_SPAN
+        return self._timed_wait(name)
+
+    @contextlib.contextmanager
+    def _timed_wait(self, name: str):
+        with self.tel.span(name):
+            self.worker_idle_since = t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.worker_idle_s += time.perf_counter() - t0
+                self.worker_idle_since = None
+
     def _run(self) -> None:
         while True:
             with self._nonempty:
-                while not self._pending and not self._stop:
-                    self._nonempty.wait()
+                if not self._pending and not self._stop:
+                    with self._waiting("serve.wait_for_work"):
+                        while not self._pending and not self._stop:
+                            self._nonempty.wait()
                 if not self._pending:
                     return                          # stopped and drained
                 # dwell: give the batch a bounded chance to fill toward
                 # the AIMD target before dispatching a fragment
                 if (self._pending_ops < self.sizer.target
                         and not self._stop and self.cfg.dwell_s > 0):
-                    self._nonempty.wait(self.cfg.dwell_s)
+                    with self._waiting("serve.dwell"):
+                        self._nonempty.wait(self.cfg.dwell_s)
                     if not self._pending:
                         continue
                 depth_at_dispatch = self._pending_ops
@@ -333,16 +371,17 @@ class RequestBatcher:
                 n = sum(r.n_ops for r in group)
                 self._pending_ops -= n
                 self._inflight += n
-            self._dispatch(group, n, depth_at_dispatch)
-            with self._idle:
-                self._inflight -= n
-                if not self._pending and not self._inflight:
-                    self._idle.notify_all()
+            with self.tel.span("serve.dispatch", n_ops=n):
+                self._dispatch(group, n, depth_at_dispatch)
+                with self._idle:
+                    self._inflight -= n
+                    if not self._pending and not self._inflight:
+                        self._idle.notify_all()
 
     def _dispatch(self, group: list[Request], n: int,
                   depth_ops: int) -> None:
         tel = self.tel
-        tracing = tel is not None and tel.enabled and tel.trace.enabled
+        tracing = tel.enabled and tel.trace.enabled
         # the member requests' ids become the worker thread's trace
         # context: every span/event recorded while this batch executes —
         # serve.queue_wait/exec, the facade op, the WAL append, a merge
@@ -355,24 +394,19 @@ class RequestBatcher:
                          depth_ops: int, tracing: bool) -> None:
         tel = self.tel
         t0 = time.perf_counter()
-        if tel is not None and tel.enabled:
+        if tel.enabled:
             tel.record_span("serve.queue_wait", t0 - group[0].t_submit)
-            tel.metrics.gauge("serve.queue_depth_ops", depth_ops)
-            tel.metrics.gauge("serve.batch_target_ops", self.sizer.target)
-            # batch-size histogram: lanes recorded on the ms scale, i.e.
-            # `serve.batch.ops` summary reads ms_* keys AS lane counts
-            tel.metrics.observe("serve.batch.ops", n * 1e-3)
         try:
-            self._execute(group)
+            with tel.span("serve.exec", op=group[0].op, n_ops=n,
+                          n_requests=len(group)):
+                self._execute(group)
             err = None
         except BaseException as e:          # noqa: BLE001 — fan the error
             err = e                         # out to every waiting client
         service_s = time.perf_counter() - t0
-        if tel is not None and tel.enabled:
-            tel.record_span("serve.exec", service_s, op=group[0].op,
-                            n_ops=n, n_requests=len(group))
-        self.n_batches += 1
-        self.batch_ops.append(n)
+        with self._lock:
+            self.n_batches += 1
+            self.batch_ops_total += n
         self.sizer.observe(depth_ops, service_s)
         t_done = time.perf_counter()
         for r in group:
@@ -383,7 +417,7 @@ class RequestBatcher:
                 self.n_failed += r.n_ops
             else:
                 self.n_completed += r.n_ops
-            if tel is not None and tel.enabled:
+            if tel.enabled:
                 tel.metrics.observe(f"serve.e2e.{r.op}",
                                     t_done - r.t_arrival)
                 if tracing:
@@ -402,28 +436,29 @@ class RequestBatcher:
         Commit order == execution order == journal order."""
         op = group[0].op
         ix = self.index
-        t_done: float | None = None
         if op == "lookup":
             q = np.concatenate([r.keys for r in group])
             v, f = ix.lookup(q)
-            self._journal(OpBatch("lookup", keys=q))
-            t_done = time.perf_counter()
-            i = 0
-            for r in group:
-                j = i + r.n_ops
-                r._complete((v[i:j], f[i:j]), t_done=t_done)
-                i = j
+            with self.tel.span("serve.complete"):
+                self._journal(OpBatch("lookup", keys=q))
+                t_done = time.perf_counter()
+                i = 0
+                for r in group:
+                    j = i + r.n_ops
+                    r._complete((v[i:j], f[i:j]), t_done=t_done)
+                    i = j
         elif op == "range":
             lo = np.concatenate([r.lo for r in group])
             hi = np.concatenate([r.hi for r in group])
             ks, vs, cnt = ix.range(lo, hi, max_hits=group[0].max_hits)
-            self._journal(OpBatch("range", lo=lo, hi=hi))
-            t_done = time.perf_counter()
-            i = 0
-            for r in group:
-                j = i + r.n_ops
-                r._complete((ks[i:j], vs[i:j], cnt[i:j]), t_done=t_done)
-                i = j
+            with self.tel.span("serve.complete"):
+                self._journal(OpBatch("range", lo=lo, hi=hi))
+                t_done = time.perf_counter()
+                i = 0
+                for r in group:
+                    j = i + r.n_ops
+                    r._complete((ks[i:j], vs[i:j], cnt[i:j]), t_done=t_done)
+                    i = j
         elif op == "upsert":
             keys = np.concatenate([r.keys for r in group])
             vals = np.concatenate([r.vals for r in group])
@@ -431,15 +466,17 @@ class RequestBatcher:
             # write to the same key wins (overlay merge is last-write-wins
             # in array order — the same rule the oracle replay applies)
             ix.upsert(keys, vals)
-            self._journal(OpBatch("upsert", keys=keys, vals=vals))
-            t_done = time.perf_counter()
-            for r in group:
-                # the ack: WAL append (when armed) + overlay apply are done
-                r._complete(t_done=t_done)
+            self._ack(group, OpBatch("upsert", keys=keys, vals=vals))
         else:                                        # delete
             keys = np.concatenate([r.keys for r in group])
             ix.delete(keys)
-            self._journal(OpBatch("delete", keys=keys))
+            self._ack(group, OpBatch("delete", keys=keys))
+
+    def _ack(self, group: list[Request], batch: OpBatch) -> None:
+        """Journal an applied write batch and wake its clients: the WAL
+        append (when armed) and the overlay apply are done."""
+        with self.tel.span("serve.complete"):
+            self._journal(batch)
             t_done = time.perf_counter()
             for r in group:
                 r._complete(t_done=t_done)

@@ -11,9 +11,9 @@ import numpy as np
 import pytest
 
 from repro.api import IndexConfig, LearnedIndex, MaintenanceConfig
-from repro.obs import (MERGE_SPANS, NULL_TELEMETRY, OPS, RECOVERY_SPANS,
-                       LatencyHistogram, MetricsRegistry, Telemetry,
-                       latency_summary, watchdog)
+from repro.obs import (ENGINE_SPANS, MERGE_SPANS, NULL_TELEMETRY, OPS,
+                       RECOVERY_SPANS, LatencyHistogram, MetricsRegistry,
+                       Telemetry, latency_summary, watchdog)
 
 ENGINES = ("local", "pallas", "sharded")
 
@@ -96,7 +96,8 @@ def test_telemetry_snapshot_fixed_taxonomy():
     snap = t.snapshot()
     assert snap["schema"] == "dili.metrics/1"
     assert set(snap["ops"]) == set(OPS)
-    assert set(snap["spans"]) == set(MERGE_SPANS + RECOVERY_SPANS)
+    assert set(snap["spans"]) == set(MERGE_SPANS + RECOVERY_SPANS
+                                     + ENGINE_SPANS)
     # recovery.* spans are pre-declared: zero-filled summaries with the
     # full latency_summary key set BEFORE any recovery has ever run, so
     # a fresh index and a recovered one export the same schema
@@ -213,6 +214,66 @@ def test_metrics_schema_equivalent_across_engines():
         shapes[engine] = shape(m)
         ix.close()
     assert shapes["local"] == shapes["pallas"] == shapes["sharded"]
+
+
+@pytest.mark.parametrize("engine,per_lookup,whats", [
+    ("local", 1, {"result"}),
+    ("pallas", 3, {"route", "recheck", "result"})])
+def test_host_syncs_per_lookup(engine, per_lookup, whats):
+    """Each blocking device-to-host read is one `engine.fetch` span and
+    one `engine.host_syncs` count: the local engine reads its results
+    once; the Pallas engine also reads the kernel's route flag and the
+    recheck's patched-lane count.  Pending writes add no read."""
+    keys, vals = _universe()
+    ix = LearnedIndex.build(keys, vals, config=IndexConfig(
+        engine=engine, telemetry=True))
+    tel = ix.telemetry
+    ix.lookup(keys[:64])
+    for pending in (False, True):
+        if pending:
+            ix.upsert(keys[:4] + 1.0, np.arange(4))
+        syncs = tel.metrics.counters["engine.host_syncs"]
+        fetches = tel.spans.count("engine.fetch")
+        for _ in range(5):
+            ix.lookup(keys[:64])
+        assert tel.metrics.counters["engine.host_syncs"] - syncs == \
+            5 * per_lookup
+        assert tel.spans.count("engine.fetch") - fetches == 5 * per_lookup
+    got = {s.attrs["what"] for s in tel.spans.spans("engine.fetch")}
+    assert got == whats
+    ix.close()
+
+
+def test_disabled_telemetry_records_and_annotates_nothing(monkeypatch):
+    """Telemetry off: no span, no sync count, and no profiler annotation
+    is even built on the served path, collections included."""
+    import gc
+    from repro.obs import telemetry as T, tracing
+    from repro.serve import ServeFrontend
+    built = []
+
+    def spy(name, **attrs):
+        built.append(name)
+
+    monkeypatch.setattr(tracing, "trace_annotation", spy)
+    monkeypatch.setattr(T, "trace_annotation", spy)
+    keys, vals = _universe()
+    for on in (False, True):
+        ix = LearnedIndex.build(keys, vals, config=IndexConfig(
+            engine="local", telemetry=on))
+        fe = ServeFrontend(ix)
+        c = fe.client("c")
+        c.lookup(keys[:3])
+        c.upsert(keys[:2] + 1.0, [1, 2])
+        gc.collect()
+        fe.close()
+        m = ix.metrics()
+        ix.close()
+        if not on:
+            assert built == []
+            assert all(s["count"] == 0 for s in m["spans"].values())
+            assert m["counters"]["engine.host_syncs"] == 0
+    assert {"serve.exec", "engine.fetch", "host.gc"} <= set(built)
 
 
 def test_stats_shared_across_engines():

@@ -26,7 +26,8 @@ import numpy as np
 import pytest
 
 from repro.api import IndexConfig, LearnedIndex
-from repro.obs.tracing import MERGE_SPANS, RECOVERY_SPANS, SERVE_SPANS
+from repro.obs.tracing import (ENGINE_SPANS, GC_SPAN, MERGE_SPANS,
+                               RECOVERY_SPANS, SERVE_SPANS)
 from repro.serve import (AdaptiveBatchSizer, RejectedError, Request,
                          RequestBatcher, ServeConfig, ServeFrontend,
                          SessionTable, coalesce, open_loop, pow2_bucket)
@@ -214,21 +215,136 @@ def test_serve_spans_declared_only_on_attach():
         engine="local", telemetry=True))
     try:
         base_snap = ix.metrics()
-        assert set(base_snap["spans"]) == set(MERGE_SPANS + RECOVERY_SPANS)
+        bare = set(MERGE_SPANS + RECOVERY_SPANS + ENGINE_SPANS)
+        assert set(base_snap["spans"]) == bare
         assert base_snap["serve"] == {}      # bare index: no serve block
         fe = ServeFrontend(ix)
         fe.client("c").lookup([0.0])
+        fe.drain()              # the worker records after waking clients
         snap = ix.metrics()
-        assert set(snap["spans"]) == \
-            set(MERGE_SPANS + RECOVERY_SPANS) | set(SERVE_SPANS)
+        assert set(snap["spans"]) == bare | set(SERVE_SPANS) | {GC_SPAN}
         for op in ("lookup", "range", "upsert", "delete"):
             assert f"serve.e2e.{op}" in snap["serve"]
         assert snap["serve"]["serve.e2e.lookup"]["count"] >= 1
-        assert snap["serve"]["serve.batch.ops"]["count"] >= 1
+        assert fe.stats()["batch_ops_mean"] >= 1
         assert snap["spans"]["serve.exec"]["count"] >= 1
         fe.close()
     finally:
         ix.close()
+
+
+def _host_events(xplane_dir):
+    """{host line: [(name, start_ns, end_ns), ...]} of the program's spans
+    in a `jax.profiler` trace."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    pb = glob.glob(os.path.join(xplane_dir, "**", "*.xplane.pb"),
+                   recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(pb[0]).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                ev = [(e.name, e.start_ns, e.end_ns) for e in line.events
+                      if e.name.startswith(("serve.", "engine.", "host."))]
+                if ev:
+                    out[(plane.name, i)] = ev
+    return out
+
+
+def test_worker_spans_on_the_profiler_trace_nest_and_order(tmp_path):
+    """With telemetry on, the worker's spans are profiler annotations: on
+    one host line, each `serve.exec` lies inside a `serve.dispatch` and
+    follows a `serve.wait_for_work` or `serve.dwell` that ended before it
+    began, and every `engine.*` and `serve.complete` span lies inside a
+    `serve.exec`."""
+    import jax
+    ix = LearnedIndex.build(np.arange(0.0, 512.0, 2.0), config=IndexConfig(
+        engine="local", telemetry=True))
+    ix.lookup(np.arange(8.0))                  # compile outside the trace
+    fe = ServeFrontend(ix, ServeConfig(dwell_s=0.001))
+    c = fe.client("c")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(6):
+            c.lookup([2.0 * i])
+        c.upsert([3.0], [30])
+        c.lookup([3.0])
+    finally:
+        jax.profiler.stop_trace()
+        fe.close()
+        ix.close()
+    lines = [ev for ev in _host_events(tmp_path).values()
+             if any(n == "serve.exec" for n, _, _ in ev)]
+    assert len(lines) == 1                     # the one worker thread
+    ev = sorted(lines[0], key=lambda e: e[1])
+    execs = [(s, e) for n, s, e in ev if n == "serve.exec"]
+    turns = [(s, e) for n, s, e in ev if n == "serve.dispatch"]
+    assert len(execs) == len(turns) == 8
+    for n, s, e in ev:
+        if n.startswith("engine.") or n == "serve.complete":
+            assert any(x0 <= s and e <= x1 for x0, x1 in execs), (n, s, e)
+    for x0, x1 in execs:
+        assert any(t0 <= x0 and x1 <= t1 for t0, t1 in turns)
+    names = {n for n, _, _ in ev}
+    assert {"engine.prep", "engine.upload", "engine.launch",
+            "engine.fetch", "engine.write", "serve.complete"} <= names
+    for x0, x1 in execs:
+        waits = [e for n, s, e in ev
+                 if n in ("serve.wait_for_work", "serve.dwell") and e <= x0]
+        assert waits, x0
+        assert not [n for n, s, e in ev if n in ("serve.wait_for_work",
+                                                 "serve.dwell")
+                    and s < x1 and e > x0]
+
+
+def test_gc_spans_hooked_while_served_and_unhooked_at_close():
+    import gc
+    ix = LearnedIndex.build(np.arange(64.0), config=IndexConfig(
+        engine="local", telemetry=True))
+    try:
+        fe = ServeFrontend(ix)
+        hook = ix.telemetry._gc_hook
+        assert hook in gc.callbacks
+        gc.collect()
+        n = ix.metrics()["spans"][GC_SPAN]["count"]
+        assert n >= 1
+        assert {"generation": 2} in [
+            sp.attrs for sp in ix.telemetry.spans.spans(GC_SPAN)]
+        fe.close()
+        assert hook not in gc.callbacks
+        gc.collect()
+        assert ix.metrics()["spans"][GC_SPAN]["count"] == n
+    finally:
+        ix.close()
+
+
+@pytest.mark.parametrize("telemetry", [True, False])
+def test_worker_idle_time_in_stats(telemetry):
+    """`stats()` carries the worker's idle seconds (finished waits) and
+    the start of the wait in progress, while telemetry is on."""
+    import time
+    ix = LearnedIndex.build(np.arange(64.0), config=IndexConfig(
+        engine="local", telemetry=telemetry))
+    fe = ServeFrontend(ix, ServeConfig(dwell_s=0.0))
+    try:
+        time.sleep(0.05)
+        s0 = fe.stats()
+        fe.client("c").lookup([1.0])
+        time.sleep(0.05)
+        s1 = fe.stats()
+    finally:
+        fe.close()
+        ix.close()
+    if not telemetry:
+        assert s1["worker_idle_s"] == 0.0
+        assert s1["worker_idle_since"] is None
+        return
+    assert s0["worker_idle_since"] is not None   # waiting for work
+    assert s0["worker_idle_s"] == 0.0            # no wait has ended yet
+    assert s1["worker_idle_s"] >= 0.05           # that wait, now ended
+    assert s1["worker_idle_since"] > s0["worker_idle_since"]
+    assert s1["batch_ops_mean"] == 1.0
 
 
 # -- engine layer: the concurrency contract -----------------------------------
