@@ -32,9 +32,9 @@ from ..core.flat import FlatDILI
 class DeviceSnapshot:
     """Immutable device snapshot of one flattened DILI.
 
-    `arrays` holds every device table (`a/b/base/fo/dense/tag/key/val`,
-    the sorted pair table, `root`, and the packed row mirrors when the
-    dtype supports them).  `max_depth` / `has_dense` / `dtype` are static
+    `arrays` holds every device table as a 1-D column
+    (`a/b/base/fo/dense/tag/key/val` and the sorted pair table) plus
+    `root`.  `max_depth` / `has_dense` / `dtype` are static
     metadata: they parameterize the compiled search, not its operands.
     """
 

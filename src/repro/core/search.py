@@ -2,9 +2,12 @@
 
 Level-synchronous traversal: a batch of Q queries advances together through
 the unified node/slot tables (flat.py).  Each round costs one FMA + floor +
-clamp + two gathers per query — the TPU adaptation of Algorithm 6's pointer
-chase.  Dense (DILI-LO) leaves exit the loop and run the paper's exponential
-search (Algorithm 1) as a bounded vectorized probe sequence.
+clamp and a handful of 1-D column gathers per query — the TPU adaptation of
+Algorithm 6's pointer chase.  Every table is a 1-D column, which XLA:TPU
+lays out lane-dense; a 2-D row mirror with a narrow minor dimension would be
+padded to 128 lanes per row and re-laid out on every call.  Dense (DILI-LO)
+leaves exit the loop and run the paper's exponential search (Algorithm 1) as
+a bounded vectorized probe sequence.
 
 Cost model (DESIGN.md section 9): traversal work is *depth-exact* — the trip
 count is the snapshot's true `max_depth` (derived via `resolve_max_depth`,
@@ -59,32 +62,19 @@ def device_arrays(flat: FlatDILI, dtype=jnp.float64, pad: bool = True) -> dict:
     """Upload the snapshot; pads table lengths to powers of two so republishes
     reuse the compiled search executable.
 
-    Besides the column tables, the hot traversal reads two row-packed
-    mirrors: `node_pack` [n_nodes, 4] = (a, b, base, fo*±1 with the sign
-    carrying the dense flag) and `slot_pack` [n_slots, 2] = (key, tag).  One
-    level of the walk is then 3 gathers (node row, slot row, payload) instead
-    of 8 — each gather is a full memory pass over the batch, so this is the
-    single biggest lever on lookup cost.  base/fo are exact in the float
-    mantissa (<2^53 at f64; the f32 path keeps tables under 2^24 slots by
-    the VMEM-budget dispatch).
+    Every table is a 1-D column (the traversal, the dense probe, the range
+    bisection and the epoch publisher's retrace detection all read these).
     """
     f = flat
     conv = (lambda x, fill: _pad_pow2(x, fill)) if pad else (lambda x, fill: x)
-    av = conv(np.asarray(f.a), 0.0)
-    bv = conv(np.asarray(f.b), 0.0)
-    basev = conv(f.base, 0)
-    fov = conv(f.fo, 1)
-    densev = conv(f.dense, 0)
-    tagv = conv(f.tag, TAG_EMPTY)
-    keyv = conv(f.key, 0.0)
-    out = dict(
-        a=jnp.asarray(av, dtype),
-        b=jnp.asarray(bv, dtype),
-        base=jnp.asarray(basev, jnp.int32),
-        fo=jnp.asarray(fov, jnp.int32),
-        dense=jnp.asarray(densev, jnp.int8),
-        tag=jnp.asarray(tagv, jnp.int8),
-        key=jnp.asarray(keyv, dtype),
+    return dict(
+        a=jnp.asarray(conv(np.asarray(f.a), 0.0), dtype),
+        b=jnp.asarray(conv(np.asarray(f.b), 0.0), dtype),
+        base=jnp.asarray(conv(f.base, 0), jnp.int32),
+        fo=jnp.asarray(conv(f.fo, 1), jnp.int32),
+        dense=jnp.asarray(conv(f.dense, 0), jnp.int8),
+        tag=jnp.asarray(conv(f.tag, TAG_EMPTY), jnp.int8),
+        key=jnp.asarray(conv(f.key, 0.0), dtype),
         # payloads keep the snapshot's int64 width — serving payloads (KV slot
         # ids, document offsets) may exceed 2^31 (requires x64; under x32 jax
         # silently narrows, matching the f32 kernel path)
@@ -101,20 +91,6 @@ def device_arrays(flat: FlatDILI, dtype=jnp.float64, pad: bool = True) -> dict:
         # probe (32 fixed gather trips) is skipped unless one exists
         has_dense=bool(np.asarray(f.dense).any()),
     )
-    # packed mirrors need slot indices exact in the float mantissa; a narrow
-    # dtype on a big table falls back to the column layout.  The columns stay
-    # resident alongside the mirrors: the dense probe reads tag/key, the
-    # post-loop dense check reads dense, and the epoch publisher's retrace
-    # detection keys on column shapes — the mirrors only add ~50% node/slot
-    # bytes, cheap next to a second hot-path memory pass per level.
-    if jnp.finfo(dtype).nmant >= 52 or len(tagv) < (1 << 24):
-        out["node_pack"] = jnp.asarray(np.stack(
-            [av, bv, basev.astype(np.float64),
-             (fov * np.where(densev > 0, -1, 1)).astype(np.float64)],
-            axis=1), dtype)
-        out["slot_pack"] = jnp.asarray(
-            np.stack([keyv, tagv.astype(np.float64)], axis=1), dtype)
-    return out
 
 
 def as_snapshot_dict(idx) -> dict:
@@ -167,31 +143,14 @@ def _traverse_step(idx: dict, q, state, with_stats: bool):
         n, done, val, found, nodes, probes = state
     else:
         n, done, val, found = state
-    if "node_pack" in idx:
-        # row-packed fast path: one node-row gather + one slot-row gather
-        # (+ the payload) instead of eight scalar-column gathers per level
-        npk = idx["node_pack"][n]                   # [Q, 4]
-        a = npk[..., 0]
-        b = npk[..., 1]
-        base = npk[..., 2].astype(jnp.int32)
-        fo_s = npk[..., 3].astype(jnp.int32)
-        is_dense = fo_s < 0
-        fo = jnp.where(is_dense, -fo_s, fo_s)
-        pos = predict_slot(a, b, q, fo)
-        s = base + pos
-        spk = idx["slot_pack"][s]                   # [Q, 2]
-        sk = spk[..., 0]
-        t = spk[..., 1].astype(jnp.int8)
-    else:
-        # column layout (stacked shard tables, kernel fallback dicts)
-        a = idx["a"][n]
-        b = idx["b"][n]
-        fo = idx["fo"][n]
-        is_dense = idx["dense"][n] > 0
-        pos = predict_slot(a, b, q, fo)
-        s = idx["base"][n] + pos
-        t = idx["tag"][s]
-        sk = idx["key"][s]
+    a = idx["a"][n]
+    b = idx["b"][n]
+    fo = idx["fo"][n]
+    is_dense = idx["dense"][n] > 0
+    pos = predict_slot(a, b, q, fo)
+    s = idx["base"][n] + pos
+    t = idx["tag"][s]
+    sk = idx["key"][s]
     sv = idx["val"][s]
     step_active = ~done & ~is_dense
     is_child = (t == TAG_CHILD) & step_active

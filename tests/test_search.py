@@ -259,3 +259,42 @@ def test_range_query_batch_max_hits_truncation(snap):
     assert np.all(np.diff(got_k) >= 0)              # sorted ascending
     for k, v in zip(got_k, got_v):
         assert k in expect and expect[k] == v
+
+
+@pytest.mark.parametrize("dense", [False, True], ids=["no_dense", "dense"])
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32],
+                         ids=["f64", "f32"])
+def test_column_snapshot_matches_oracle(dtype, dense):
+    """Every snapshot table is a 1-D column, in f64 and in f32, and both
+    the scan and the fused early-exit walk over those columns answer
+    exactly what `SortedOracle` does — with and without dense leaves."""
+    from repro.api import DeviceSnapshot
+    from repro.kernels.ops import build_f32_index
+    from repro.online.overlay import TombstoneOverlay, overlay_device_arrays
+    from repro.workloads.oracle import SortedOracle
+    rng = np.random.default_rng(16)
+    if dtype == jnp.float32:
+        # f32 placement falls back to a few dense leaves on skewed keys;
+        # ordered record numbers (YCSB insertorder=ordered) need none
+        keys = np.arange(6000.0) if not dense else make_keys("logn", 6000,
+                                                            rng)
+        d, keys32 = build_f32_index(keys, local_optimized=not dense)
+        keys = keys32.astype(np.float64)
+    else:
+        keys = make_keys("logn", 6000, rng)
+        d = bulk_load(keys, local_optimized=not dense)
+    snap = DeviceSnapshot.from_flat(flatten(d), dtype=dtype)
+    assert snap.has_dense == dense
+    assert "node_pack" not in snap.arrays and "slot_pack" not in snap.arrays
+    assert all(np.ndim(v) <= 1 for v in snap.arrays.values())
+
+    qi = rng.integers(0, len(keys) - 1, 4096)
+    mids = ((keys[qi] + keys[qi + 1]) / 2).astype(np.dtype(dtype))
+    q = np.concatenate([keys[qi].astype(np.dtype(dtype)), mids])
+    want_v, want_f = SortedOracle(keys).lookup(q.astype(np.float64))
+    assert want_f.any() and not want_f.all()
+    ova = overlay_device_arrays(TombstoneOverlay.empty(64), dtype)
+    for v, fnd in (S.search_batch(snap, jnp.asarray(q)),
+                   S.search_with_overlay(snap, ova, jnp.asarray(q))):
+        np.testing.assert_array_equal(np.asarray(fnd), want_f)
+        np.testing.assert_array_equal(np.asarray(v)[want_f], want_v[want_f])

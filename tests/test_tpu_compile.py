@@ -13,6 +13,7 @@ this file.  The persistent compilation cache is off around these compiles
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,14 +69,15 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _snapshot_shapes(sharding, lead=()):
+def _snapshot_shapes(sharding, lead=(), kdt=jnp.float64):
     """The local engine's f64 snapshot tables as shapes (`lead` prepends
-    the shard axis of the sharded engine's stacked tables)."""
-    f64, i64, i32, i8 = jnp.float64, jnp.int64, jnp.int32, jnp.int8
-    cols = dict(a=(N_NODES, f64), b=(N_NODES, f64), base=(N_NODES, i32),
+    the shard axis of the sharded engine's stacked tables; `kdt=float32`
+    gives the Pallas engine's f32 snapshot)."""
+    i64, i32, i8 = jnp.int64, jnp.int32, jnp.int8
+    cols = dict(a=(N_NODES, kdt), b=(N_NODES, kdt), base=(N_NODES, i32),
                 fo=(N_NODES, i32), dense=(N_NODES, i8), tag=(N_SLOTS, i8),
-                key=(N_SLOTS, f64), val=(N_SLOTS, i64),
-                pair_key=(N_PAIRS, f64), pair_val=(N_PAIRS, i64))
+                key=(N_SLOTS, kdt), val=(N_SLOTS, i64),
+                pair_key=(N_PAIRS, kdt), pair_val=(N_PAIRS, i64))
     return {k: _shape(lead + (n,), dt, sharding)
             for k, (n, dt) in cols.items()}
 
@@ -101,22 +103,51 @@ def test_kernel_compiles(one_chip, no_persistent_cache, n_nodes, n_slots):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# -- the local engine's fused f64 lookup and range -----------------------------
+# -- the fused lookups (f64 local, f32 Pallas snapshot) and the f64 range ------
+
+#: a `copy` or `custom-call` instruction: its result dims and first operand
+_HLO_OP = re.compile(r"= \w+\[([\d,]*)\]\S* (copy|custom-call)\(%([\w.-]+)")
+
+
+def _compile_fused_lookup(one_chip, kdt):
+    idx = _snapshot_shapes(one_chip, kdt=kdt)
+    idx["root"] = _shape((), jnp.int32, one_chip)
+    idx["max_depth"] = _shape((), jnp.int32, one_chip)
+    ov = dict(keys=_shape((8192,), kdt, one_chip),
+              vals=_shape((8192,), jnp.int64, one_chip),
+              tomb=_shape((8192,), jnp.int8, one_chip))
+    q = _shape((N_QUERIES,), kdt, one_chip)
+    fn = jax.jit(lambda idx, ov, q: S.search_with_overlay(
+        dict(idx, has_dense=False), ov, q, max_depth=MAX_DEPTH))
+    return fn.lower(idx, ov, q).compile()
+
+
+def _assert_tables_read_in_place(compiled):
+    """The walk reads each snapshot table where it lies.  A 2-D table with
+    a narrow minor dimension is padded to 128 lanes per row in a TPU tile,
+    so XLA:TPU re-lays it out (a `copy`) and splits it into 32-bit halves
+    of rank 2 on every call, with scratch many times the table's size.
+    (Copying the scalar `root` into scalar memory is not a relayout.)"""
+    ops = [m.groups() + (line,) for line in compiled.as_text().splitlines()
+           if (m := _HLO_OP.search(line))]
+    assert ops, "no copy or custom-call parsed: has the HLO text changed?"
+    for dims, op, operand, line in ops:
+        rank = len(dims.split(",")) if dims else 0
+        if op == "copy":
+            assert not (operand.startswith("idx__") and rank), line
+        elif "X64Split" in line:
+            assert rank < 2, line
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
 def test_fused_f64_lookup_compiles(one_chip, no_persistent_cache):
-    idx = _snapshot_shapes(one_chip)
-    idx["node_pack"] = _shape((N_NODES, 4), jnp.float64, one_chip)
-    idx["slot_pack"] = _shape((N_SLOTS, 2), jnp.float64, one_chip)
-    idx["root"] = _shape((), jnp.int32, one_chip)
-    idx["max_depth"] = _shape((), jnp.int32, one_chip)
-    ov = dict(keys=_shape((8192,), jnp.float64, one_chip),
-              vals=_shape((8192,), jnp.int64, one_chip),
-              tomb=_shape((8192,), jnp.int8, one_chip))
-    q = _shape((N_QUERIES,), jnp.float64, one_chip)
-    fn = jax.jit(lambda idx, ov, q: S.search_with_overlay(
-        dict(idx, has_dense=False), ov, q, max_depth=MAX_DEPTH))
-    fn.lower(idx, ov, q).compile()
+    _assert_tables_read_in_place(_compile_fused_lookup(one_chip,
+                                                       jnp.float64))
+
+
+def test_fused_f32_lookup_compiles(one_chip, no_persistent_cache):
+    _assert_tables_read_in_place(_compile_fused_lookup(one_chip,
+                                                       jnp.float32))
 
 
 def test_f64_range_compiles(one_chip, no_persistent_cache):
