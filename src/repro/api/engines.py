@@ -37,7 +37,7 @@ from ..core.distributed import (build_sharded, combined_overlay_arrays,
                                 sharded_delete, sharded_lookup,
                                 sharded_merge, sharded_range_query,
                                 sharded_upsert, shard_of, to_mesh)
-from ..core.flat import flatten, merge_sorted_runs
+from ..core.flat import TAG_PAIR, flatten, merge_sorted_runs
 from ..maintain import (IncrementalFlattener, LeafAccounting,
                         fold_with_accounting, run_reclusters, run_retrains)
 from ..obs import Telemetry, watchdog
@@ -568,6 +568,16 @@ class LocalEngine(EngineTelemetryBase):
 # ---------------------------------------------------------------------------
 
 
+def _dense_leaf_key_share(flat) -> float:
+    """Share of the snapshot's pairs held in dense (DILI-LO) leaves: the
+    share of lookups of loaded keys that end in the kernel's rank count.
+    A dense leaf packs its pairs into its `fo` slots; an empty one keeps
+    a single EMPTY slot."""
+    d = flat.dense > 0
+    held = flat.fo[d][flat.tag[flat.base[d]] == TAG_PAIR].sum()
+    return float(held) / max(flat.n_pairs, 1)
+
+
 class PallasEngine(EngineTelemetryBase):
     """f32 kernel engine: lookups dispatch to the Pallas kernel when the
     tables fit the configured VMEM budget (XLA fallback otherwise / for
@@ -682,6 +692,7 @@ class PallasEngine(EngineTelemetryBase):
         t0 = time.perf_counter()
         with self.telemetry.span("merge.publish"):
             self.arrs = self._K.kernel_arrays(self.flat)
+            self.dense_leaf_key_share = _dense_leaf_key_share(self.flat)
             self.snap = DeviceSnapshot.from_flat(self.flat, dtype=jnp.float32,
                                                  pad=self.cfg.pad)
             jax.block_until_ready(self.snap.arrays)
@@ -876,6 +887,7 @@ class PallasEngine(EngineTelemetryBase):
                                      <= self.cfg.vmem_budget_bytes),
                     kernel_routes=dict(self.routes),
                     recheck_patched_lanes=self.recheck_patched_lanes,
+                    dense_leaf_key_share=self.dense_leaf_key_share,
                     device_bytes=self.snap.nbytes)
 
 

@@ -9,11 +9,18 @@ Mosaic does not lower a per-lane vector gather from an arbitrary VMEM
 table, so the traversal is a per-query scalar walk: each query is read
 from SMEM, and each level of Algorithm 6 reads the node's and the slot's
 fields with a dynamic-row load of the lane-dense `(rows, 128)` table and a
-masked lane reduction to a scalar.  A dense (DILI-LO) leaf, which f32
-placement produces wherever it cannot resolve slots, is bisected over its
-packed pairs (Algorithm 1).  The walk stops at the first hit or miss, or
-after `max_depth` levels.  A model level costs eight such reads per query
-and a dense leaf about 2 + log2(fo); this is correct, not fast.
+masked lane reduction to a scalar.  The walk stops at the first hit or
+miss, or after `max_depth` levels.
+
+A dense (DILI-LO) leaf, which f32 placement produces wherever it cannot
+resolve slots, keeps its pairs packed and sorted.  Its slot for q is the
+count of its keys below q (the first key >= q of Algorithm 1), taken over
+the aligned `(8, 128)` tiles that hold the leaf: independent loads and
+compares, then one reduction, where a bisection would chain log2(fo)
+reads that each wait on the one before.  A model level costs eight reads
+per query; a dense leaf six plus the count, about fo / 1024 + 1 tile
+loads and a reduction.  The walk is still one query at a time and bound
+by the latency of its dependent reads, far below the bandwidth roofline.
 
 Depth overflow sets a `needs_fallback` flag; the wrapper re-checks those
 lanes with the pure-XLA path.
@@ -38,6 +45,7 @@ TAG_EMPTY, TAG_PAIR, TAG_CHILD = 0, 1, 2
 BLOCK_Q = 2048   # queries per grid step (one SMEM block)
 LANES = 128
 _SUBLANES = 8
+_CHUNK = _SUBLANES * LANES   # slots in one aligned (8, 128) tile
 #: VMEM headroom above the tables for the compiler's own buffers
 _VMEM_SLACK = 16 * 1024 * 1024
 
@@ -57,9 +65,8 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 def _lane_dense(x: jnp.ndarray) -> jnp.ndarray:
     """1-D table -> zero-padded (rows, 128) with rows a multiple of 8."""
-    tile = _SUBLANES * LANES
     n = x.shape[0]
-    padded = -(-n // tile) * tile
+    padded = -(-n // _CHUNK) * _CHUNK
     return jnp.pad(x, (0, padded - n)).reshape(padded // LANES, LANES)
 
 
@@ -68,6 +75,8 @@ def _kernel(root_ref, q_ref, a_ref, b_ref, base_ref, fo_ref, dense_ref,
             max_depth: int, block_q: int):
     i32 = jnp.int32
     lane = jax.lax.broadcasted_iota(i32, (1, LANES), 1)
+    sub = jax.lax.broadcasted_iota(i32, (_SUBLANES, LANES), 0)
+    lanes = jax.lax.broadcasted_iota(i32, (_SUBLANES, LANES), 1)
 
     def read(ref, i):
         # element i of a lane-dense table: load its row, keep its lane
@@ -82,18 +91,27 @@ def _kernel(root_ref, q_ref, a_ref, b_ref, base_ref, fo_ref, dense_ref,
 
         def dense_slot(n):
             # DILI-LO leaf (Algorithm 1): its pairs are packed and sorted in
-            # [base, base + fo); bisect for the first key >= q
+            # [base, base + fo), so the first key >= q sits at the count of
+            # keys < q there, taken over the (8, 128) tiles that hold them
             base, fo = read(base_ref, n), read(fo_ref, n)
+            end = base + fo
+            c0 = jax.lax.div(base, i32(_CHUNK))
+            n_chunks = jax.lax.div(end + i32(_CHUNK - 1), i32(_CHUNK)) - c0
 
-            def step(lh):
-                lo, hi = lh
-                mid = jax.lax.div(lo + hi, i32(2))
-                below = read(key_ref, base + mid) < q
-                return (jnp.where(below, mid + 1, lo),
-                        jnp.where(below, hi, mid))
+            def count(c, acc):
+                row = pl.multiple_of((c0 + c) * i32(_SUBLANES), _SUBLANES)
+                slot = (row + sub) * i32(LANES) + lanes
+                below = ((slot >= base) & (slot < end)
+                         & (key_ref[pl.ds(row, _SUBLANES), :] < q))
+                return acc + below.astype(jnp.float32)
 
-            lo, _ = jax.lax.while_loop(lambda lh: lh[0] < lh[1], step,
-                                       (i32(0), fo))
+            # counted in f32, which is exact: a leaf fits VMEM, far below
+            # 2^24 slots (an int32 sum to a scalar lowers through int64
+            # under x64, which Mosaic refuses)
+            acc = jax.lax.fori_loop(i32(0), n_chunks, count,
+                                    jnp.zeros((_SUBLANES, LANES),
+                                              jnp.float32))
+            lo = jnp.sum(acc).astype(i32)
             return base + jnp.minimum(lo, jnp.maximum(fo - 1, i32(0)))
 
         def model_slot(n):

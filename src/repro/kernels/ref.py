@@ -2,8 +2,9 @@
 
 Mirrors the kernel semantics exactly: f32 keys/models, mul-then-add slot
 prediction, fixed `max_depth` unrolled traversal, dense (DILI-LO) leaves
-bisected for the first key >= q, and only depth overflow flagged for the
-wrapper's XLA fallback (see ops.py).
+searched for the first key >= q (bisected here, where the kernel counts
+the keys below q: the same slot over sorted keys), and only depth
+overflow flagged for the wrapper's XLA fallback (see ops.py).
 """
 
 from __future__ import annotations

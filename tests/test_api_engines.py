@@ -272,6 +272,42 @@ def test_pallas_engine_warns_on_build_key_collisions():
     ix.close()
 
 
+@pytest.mark.parametrize("bulk_kw,share", [
+    (dict(local_optimized=False), 1.0),    # DILI-LO: every leaf is dense
+    ({}, 0.0),            # small ordered ids: f32 placement needs no dense leaf
+])
+def test_pallas_stats_dense_leaf_key_share(bulk_kw, share):
+    ix = LearnedIndex.build(np.arange(6000.0), config=IndexConfig(
+        engine="pallas", merge=manual_merge_policy(), bulk_kw=bulk_kw))
+    assert ix.stats()["dense_leaf_key_share"] == share
+    ix.close()
+
+
+def test_pallas_dense_leaf_key_share_follows_publish():
+    """The share is the new snapshot's after a merge empties part of a
+    dense leaf: the pairs its dense leaves hold over all pairs."""
+    from repro.core.dili import Leaf
+    from repro.core.flat import preorder
+    keys = np.arange(20000.0)   # f32 placement packs the top keys densely
+    ix = LearnedIndex.build(keys, config=IndexConfig(
+        engine="pallas", merge=manual_merge_policy()))
+
+    def dense_leaves():
+        return [nd for nd in preorder(ix.host.root)
+                if isinstance(nd, Leaf) and nd.dense]
+
+    held = sum(nd.omega for nd in dense_leaves())
+    assert 0 < held < len(keys)
+    assert ix.stats()["dense_leaf_key_share"] == held / len(keys)
+    lb = dense_leaves()[0].lb
+    ix.delete(np.arange(lb, lb + 1000))
+    ix.flush()
+    assert sum(nd.omega for nd in dense_leaves()) == held - 1000
+    assert (ix.stats()["dense_leaf_key_share"]
+            == (held - 1000) / (len(keys) - 1000))
+    ix.close()
+
+
 @pytest.mark.slow
 def test_sharded_engine_multi_device_equivalence():
     """The facade on an 8-shard mesh answers exactly like the local engine

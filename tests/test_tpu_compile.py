@@ -90,6 +90,9 @@ def _snapshot_shapes(sharding, lead=(), kdt=jnp.float64):
     (1 << 14, 1 << 19),
     # near the default VMEM budget: 5 node + 3 slot words of 4 bytes
     (1 << 16, (VMEM_BUDGET_BYTES - 20 * (1 << 16)) // 12),
+    # the multiget cell's shape: a few hundred nodes, mostly dense leaves,
+    # and a slot table that fills the budget
+    (1 << 8, (VMEM_BUDGET_BYTES - 20 * (1 << 8)) // 12),
 ])
 def test_kernel_compiles(one_chip, no_persistent_cache, n_nodes, n_slots):
     f32, i32 = jnp.float32, jnp.int32
