@@ -3,8 +3,7 @@
 Prints ``name,us_per_call,derived`` CSV rows (plus `#` section headers).
 Scaled to BENCH_N_KEYS (default 300k; the paper's 200M is one env var away).
 Lookup wall-times are CPU-JAX batched timings — the relative ordering is the
-claim under test; the TPU roofline story lives in benchmarks/roofline.py +
-EXPERIMENTS.md.
+claim under test; the TPU roofline story lives in bench/roofline.py.
 
 Cost model (since PR 2, DESIGN.md section 9): point lookups are depth-exact —
 the traversal trip count is the snapshot's true `max_depth` with batch-

@@ -1,7 +1,7 @@
 """Concurrent serving front-end over `repro.api.LearnedIndex`
 (DESIGN.md section 15): request batching/coalescing, admission control,
-adaptive batch sizing, open-loop load generation, and the LLM-serving
-session table.
+adaptive batch sizing, open-loop load generation, and the session
+table.
 """
 
 from .batcher import (AdaptiveBatchSizer, RejectedError, Request,

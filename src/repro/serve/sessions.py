@@ -1,4 +1,4 @@
-"""Serving-side session table: session-id -> KV-cache slot, through a DILI.
+"""Serving-side session table: session-id -> slot, through a DILI.
 
 Since the api redesign this sits on the public facade
 (`repro.api.LearnedIndex`): admissions are upserts, evictions are deletes,

@@ -1,10 +1,12 @@
 """Shared fixtures.  x64 is enabled for the whole test session: the index
-(key) paths need f64 and the model paths use explicit dtypes throughout."""
+(key) paths need f64."""
 import os
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import numpy as np
 import pytest
+
+from bench import keys as bench_keys
 
 
 @pytest.fixture(scope="session")
@@ -22,3 +24,18 @@ def make_keys(dist: str, n: int, rng) -> np.ndarray:
     if dist == "wikits":    # near-sequential timestamps
         return np.unique(np.cumsum(rng.integers(1, 5, n)).astype(np.float64))
     raise ValueError(dist)
+
+
+def ycsb_keys(shape: str, n: int, n_new: int):
+    """The served cells' key set: YCSB's `n` records as `bench.keys` makes
+    them ("hashed" or "ordered"), and the `n_new` keys YCSB inserts next,
+    in insert order: the following record numbers, or their fnv64 hashes
+    under insertorder=hashed, less any that float64 cannot tell from a
+    loaded or an earlier key."""
+    keys = bench_keys.make_keys(shape, n)
+    recs = np.arange(n, n + 2 * n_new + 16)
+    new = (bench_keys.fnvhash64(recs) if shape == "hashed"
+           else recs).astype(np.float64)
+    new = new[~np.isin(new, keys)]
+    _, first = np.unique(new, return_index=True)
+    return keys, new[np.sort(first)][:n_new]

@@ -7,13 +7,16 @@ f32 tolerance rule: the Pallas engine quantizes keys to f32 at the
 boundary, so the shared key set is integer-valued below 2^24 (exactly
 f32-representable) and payloads stay below 2^31 (the kernel path's int32
 payload width) — under those conditions the engines must agree bit-exactly,
-not approximately.
+not approximately.  A second fleet holds the local_hashed cells' key set
+(YCSB's fnv64 record hashes, `bench/keys.py`), beyond f32's exact range,
+so it compares the local and sharded engines only.
 """
 import numpy as np
 import pytest
 
 from repro.api import (IndexConfig, LearnedIndex, MaintenanceConfig,
                        manual_merge_policy)
+from tests.conftest import ycsb_keys
 
 ENGINES = ("local", "pallas", "sharded")
 
@@ -26,19 +29,33 @@ def _keyset(rng):
     return keys, vals
 
 
-@pytest.fixture(scope="module")
-def fleet():
+# each fleet's key set and the engines that serve it
+FLEETS = {"int": ENGINES, "ycsb_hashed": ("local", "sharded")}
+
+
+@pytest.fixture(scope="module", params=sorted(FLEETS))
+def fleet(request):
+    """The fleet's engines over one key set, and `fresh`: sorted keys
+    never loaded — fixed integers above the int set, or the hashes of the
+    records YCSB inserts next."""
     rng = np.random.default_rng(99)
-    keys, vals = _keyset(rng)
+    if request.param == "int":
+        keys, vals = _keyset(rng)
+        fresh = np.setdiff1d(
+            np.arange(1, 200, dtype=np.float64) * 7 + (1 << 22), keys)
+    else:
+        keys, fresh = ycsb_keys("hashed", 4000, 96)
+        fresh = np.sort(fresh)
+        vals = rng.integers(0, 1 << 30, len(keys)).astype(np.int64)
     cfg = IndexConfig(merge=manual_merge_policy(), overlay_cap=256)
     ixs = {e: LearnedIndex.build(keys, vals, config=cfg.with_engine(e))
-           for e in ENGINES}
-    return keys, vals, ixs, rng
+           for e in FLEETS[request.param]}
+    return keys, vals, ixs, rng, fresh
 
 
 def _assert_lookup_equivalent(ixs, queries):
     ref_v, ref_f = ixs["local"].lookup(queries)
-    for e in ENGINES[1:]:
+    for e in ixs.keys() - {"local"}:
         v, f = ixs[e].lookup(queries)
         np.testing.assert_array_equal(f, ref_f, err_msg=e)
         np.testing.assert_array_equal(v[f], ref_v[ref_f], err_msg=e)
@@ -47,7 +64,7 @@ def _assert_lookup_equivalent(ixs, queries):
 
 def _assert_range_equivalent(ixs, lo, hi, max_hits=64):
     ref = ixs["local"].range(lo, hi, max_hits=max_hits)
-    for e in ENGINES[1:]:
+    for e in ixs.keys() - {"local"}:
         ks, vs, cnt = ixs[e].range(lo, hi, max_hits=max_hits)
         np.testing.assert_array_equal(cnt, ref[2], err_msg=e)
         np.testing.assert_array_equal(ks, ref[0], err_msg=e)
@@ -56,17 +73,17 @@ def _assert_range_equivalent(ixs, lo, hi, max_hits=64):
 
 
 def test_lookup_equivalence_fresh(fleet):
-    keys, vals, ixs, rng = fleet
+    keys, vals, ixs, rng, fresh = fleet
     qi = rng.integers(0, len(keys), 2048)
-    # the +2^23 shift pushes queries past every key: guaranteed misses
-    q = np.concatenate([keys[qi], keys[qi[:16]] + (1 << 23)])
+    # fresh keys were never loaded: guaranteed misses
+    q = np.concatenate([keys[qi], fresh[:16]])
     v, f = _assert_lookup_equivalent(ixs, q)
     assert f[: len(qi)].all()
     np.testing.assert_array_equal(v[: len(qi)], vals[qi])
 
 
 def test_range_equivalence_fresh(fleet):
-    keys, vals, ixs, rng = fleet
+    keys, vals, ixs, rng, _ = fleet
     starts = rng.integers(0, len(keys) - 100, 128)
     lo, hi = keys[starts], keys[starts + rng.integers(1, 90, 128)]
     ks, vs, cnt = _assert_range_equivalent(ixs, lo, hi)
@@ -78,9 +95,8 @@ def test_range_equivalence_fresh(fleet):
 
 
 def test_write_and_delete_visibility_equivalence(fleet):
-    keys, vals, ixs, rng = fleet
-    new = np.setdiff1d(np.arange(1, 200, dtype=np.float64) * 7 + (1 << 22),
-                       keys)[:96]
+    keys, vals, ixs, rng, fresh = fleet
+    new = fresh[:96]
     new_v = np.arange(len(new), dtype=np.int64) + 5_000_000
     dead = keys[rng.integers(0, len(keys), 64)]
     for ix in ixs.values():
@@ -94,8 +110,9 @@ def test_write_and_delete_visibility_equivalence(fleet):
     assert not f[len(new): len(new) + len(dead)].any()
 
     # ranges spanning the written region agree too (overlay-exact)
-    lo = np.array([new[0] - 3, keys[0], dead.min() - 1])
-    hi = np.array([new[-1] + 3, keys[300], dead.min() + 1])
+    lo = np.array([new[0], keys[0], dead.min()])
+    hi = np.array([np.nextafter(new[-1], np.inf), keys[300],
+                   np.nextafter(dead.min(), np.inf)])
     _assert_range_equivalent(ixs, lo, hi)
 
     # post-flush: folded through Alg. 7/8 on every engine
@@ -109,7 +126,7 @@ def test_write_and_delete_visibility_equivalence(fleet):
 
     # logical content identical across engines
     k0, v0 = ixs["local"].items()
-    for e in ENGINES[1:]:
+    for e in ixs.keys() - {"local"}:
         k, v = ixs[e].items()
         np.testing.assert_array_equal(k, k0, err_msg=e)
         np.testing.assert_array_equal(v, v0, err_msg=e)

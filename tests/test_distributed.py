@@ -1,6 +1,7 @@
 """Distributed tests run in subprocesses with 8 forced host devices (the main
-test process must keep seeing 1 device — dry-run contract)."""
-import json
+test process must keep seeing 1 device).  Each runs on two key sets:
+lognormal keys, and the local_hashed cells' YCSB record hashes
+(`bench/keys.py`), whose online inserts are the next records' hashes."""
 import os
 import subprocess
 import sys
@@ -15,7 +16,7 @@ def run_sub(code: str) -> str:
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                JAX_ENABLE_X64="1",
-               PYTHONPATH=os.path.join(ROOT, "src"))
+               PYTHONPATH=os.pathsep.join([os.path.join(ROOT, "src"), ROOT]))
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=900)
@@ -23,13 +24,34 @@ def run_sub(code: str) -> str:
     return out.stdout
 
 
+# `load_keys(rng, n)` and `new_keys(rng, keys)` (up to 2048 keys never
+# loaded), defined ahead of each subprocess's code
+KEY_MAKERS = {
+    "lognormal": """
+        def load_keys(rng, n):
+            return np.unique(rng.lognormal(0, 1, n))
+        def new_keys(rng, keys):
+            return np.setdiff1d(np.unique(rng.lognormal(0, 1, 3000)),
+                                keys)[:2048]
+    """,
+    "ycsb_hashed": """
+        from tests.conftest import ycsb_keys
+        def load_keys(rng, n):
+            return ycsb_keys("hashed", n, 0)[0]
+        def new_keys(rng, keys):
+            return ycsb_keys("hashed", len(keys), 2048)[1]
+    """,
+}
+
+
 @pytest.mark.slow
-def test_sharded_index_gather_and_a2a():
-    out = run_sub("""
+@pytest.mark.parametrize("shape", sorted(KEY_MAKERS))
+def test_sharded_index_gather_and_a2a(shape):
+    out = run_sub(KEY_MAKERS[shape] + """
         import numpy as np, jax, jax.numpy as jnp
         from repro.core.distributed import build_sharded, to_mesh, sharded_lookup
         rng = np.random.default_rng(1)
-        keys = np.unique(rng.lognormal(0, 1, 40000))
+        keys = load_keys(rng, 40000)
         sd = build_sharded(keys, None, n_shards=8, sample_stride=4)
         mesh = jax.make_mesh((8,), ("data",))
         arrs = to_mesh(sd, mesh)
@@ -48,22 +70,23 @@ def test_sharded_index_gather_and_a2a():
 
 
 @pytest.mark.slow
-def test_sharded_online_updates():
+@pytest.mark.parametrize("shape", sorted(KEY_MAKERS))
+def test_sharded_online_updates(shape):
     """Per-shard overlays absorb upserts/deletes without a global rebuild;
     merge republishes only the touched shards' rows."""
-    out = run_sub("""
+    out = run_sub(KEY_MAKERS[shape] + """
         import numpy as np, jax, jax.numpy as jnp
         from repro.core.distributed import (build_sharded, to_mesh,
             sharded_lookup_with_overlay, sharded_upsert, sharded_delete,
             sharded_merge)
         rng = np.random.default_rng(2)
-        keys = np.unique(rng.lognormal(0, 1, 20000))
+        keys = load_keys(rng, 20000)
         sd = build_sharded(keys, None, n_shards=8, sample_stride=4)
         mesh = jax.make_mesh((8,), ("data",))
         arrs = to_mesh(sd, mesh)
         qi = rng.integers(0, len(keys), 4096)
         q = jnp.asarray(keys[qi])
-        new = np.setdiff1d(np.unique(rng.lognormal(0, 1, 3000)), keys)[:2048]
+        new = new_keys(rng, keys)
         sharded_upsert(sd, new, 5_000_000 + np.arange(len(new)))
         dels = np.unique(keys[qi[:512]])
         sharded_delete(sd, dels)
@@ -92,16 +115,17 @@ def test_sharded_online_updates():
 
 
 @pytest.mark.slow
-def test_sharded_range_query():
+@pytest.mark.parametrize("shape", sorted(KEY_MAKERS))
+def test_sharded_range_query(shape):
     """Per-shard sorted-pair bisection + prefix-offset psum assembly matches
     a brute-force numpy oracle, including windows spanning shard boundaries
     and max_hits truncation."""
-    out = run_sub("""
+    out = run_sub(KEY_MAKERS[shape] + """
         import numpy as np, jax, jax.numpy as jnp
         from repro.core.distributed import (build_sharded, to_mesh,
             sharded_range_query)
         rng = np.random.default_rng(7)
-        keys = np.unique(rng.lognormal(0, 1, 30000))
+        keys = load_keys(rng, 30000)
         sd = build_sharded(keys, None, n_shards=8, sample_stride=4)
         mesh = jax.make_mesh((8,), ("data",))
         arrs = to_mesh(sd, mesh)
@@ -126,90 +150,3 @@ def test_sharded_range_query():
         print("DIST-RANGE-OK")
     """)
     assert "DIST-RANGE-OK" in out
-
-
-@pytest.mark.slow
-def test_small_mesh_train_step_shardings():
-    out = run_sub("""
-        import dataclasses, jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.configs import get_config
-        from repro.models import model as MDL
-        from repro.parallel import sharding as SH
-        from repro.train import step as STEP
-        from repro.train.optim import adamw
-        cfg = dataclasses.replace(get_config("granite_8b").reduced(),
-                                  d_model=128, n_heads=4, n_kv_heads=2,
-                                  d_ff=256, vocab=512)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        opt = adamw(lr=1e-3)
-        state_shape = jax.eval_shape(
-            lambda: STEP.init_state(jax.random.PRNGKey(0), cfg, opt))
-        p_sh = SH.param_shardings(cfg, mesh, state_shape["params"])
-        # init on mesh
-        with mesh:
-            state = STEP.init_state(jax.random.PRNGKey(0), cfg, opt)
-            step = jax.jit(STEP.make_train_step(cfg, opt))
-            toks = jnp.zeros((8, 16), jnp.int32)
-            batch = dict(tokens=toks, labels=toks)
-            state2, m = step(state, batch)
-            assert np.isfinite(float(m["loss"]))
-        print("MESH-TRAIN-OK", float(m["loss"]))
-    """)
-    assert "MESH-TRAIN-OK" in out
-
-
-@pytest.mark.slow
-def test_elastic_restore_across_meshes(tmp_path):
-    out = run_sub(f"""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.configs import get_config
-        from repro.ft import checkpoint as CKPT
-        from repro.train import step as STEP
-        from repro.train.optim import adamw
-        cfg = get_config("granite_8b").reduced()
-        opt = adamw()
-        state = STEP.init_state(jax.random.PRNGKey(0), cfg, opt)
-        CKPT.save(r"{tmp_path}", 5, state)
-        # restore onto an 8-device mesh with FSDP shardings
-        from repro.parallel import sharding as SH
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
-        tmpl = jax.eval_shape(lambda: STEP.init_state(
-            jax.random.PRNGKey(0), cfg, opt))
-        p_sh = SH.param_shardings(cfg, mesh, tmpl["params"])
-        got, man = CKPT.restore(r"{tmp_path}", tmpl["params"], p_sh,
-                                prefix="params")
-        assert man["step"] == 5
-        leaf = got["layers"]["attn"]["wq"]
-        assert len(leaf.sharding.device_set) >= 1
-        np.testing.assert_allclose(
-            np.asarray(leaf), np.asarray(state["params"]["layers"]["attn"]["wq"]),
-            rtol=1e-6)
-        print("ELASTIC-OK")
-    """)
-    assert "ELASTIC-OK" in out
-
-
-@pytest.mark.slow
-def test_psum_int8_compression_collective():
-    out = run_sub("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from jax import shard_map
-        from repro.parallel.compression import psum_int8
-        mesh = jax.make_mesh((8,), ("data",))
-        def f(x):
-            return psum_int8(x, "data")
-        g = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
-        x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (64, 32)),
-                        jnp.float32)
-        y = g(x)
-        # every shard receives the same sum; compare against exact psum
-        exact = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
-                          in_specs=P("data"), out_specs=P("data"))(x)
-        err = float(jnp.abs(y - exact).max())
-        scale = float(jnp.abs(exact).max())
-        assert err < 0.05 * scale + 0.1, (err, scale)
-        print("COMPRESS-OK", err)
-    """)
-    assert "COMPRESS-OK" in out

@@ -16,6 +16,7 @@ from repro.durability import checkpoint as dckpt
 from repro.workloads.generator import PRESETS, generate_stream
 from repro.workloads.oracle import SortedOracle
 from repro.workloads.runner import WorkloadRunner
+from tests.conftest import ycsb_keys
 
 ENGINES = ("local", "pallas", "sharded")
 
@@ -130,16 +131,30 @@ def test_checkpoint_corrupt_newest_falls_back(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_abandon_recover_round_trip(tmp_path, engine):
+# key shapes and the engines that serve them
+RECOVERY_SHAPES = {"int": ENGINES, "ycsb_hashed": ("local", "sharded"),
+                   "ycsb_ordered": ENGINES}
+
+
+@pytest.mark.parametrize("engine,shape", [
+    pytest.param(e, shape, id=e if shape == "int" else f"{e}-{shape}")
+    for shape, engines in RECOVERY_SHAPES.items() for e in engines])
+def test_abandon_recover_round_trip(tmp_path, engine, shape):
     """The acknowledged write stream survives an un-fsynced abandon on
-    every engine: checkpointed prefix + WAL tail == oracle."""
+    every engine: checkpointed prefix + WAL tail == oracle.  The YCSB key
+    sets are the served cells' (`bench/keys.py`): fnv64 record hashes,
+    whose inserts are the next records' hashes (`keys + 0.5` would not
+    move them), and dense record numbers, whose inserts are tail inserts."""
     rng = np.random.default_rng(5)
-    keys = np.unique(rng.integers(0, 1 << 20, 900)).astype(np.float64)
+    if shape == "int":
+        keys = np.unique(rng.integers(0, 1 << 20, 900)).astype(np.float64)
+        up_k = keys[:40] + 0.5
+    else:
+        keys, up_k = ycsb_keys(shape.removeprefix("ycsb_"), 900, 40)
     vals = rng.integers(0, 1 << 30, len(keys)).astype(np.int64)
     oracle = SortedOracle(keys, vals)
     ix = LearnedIndex.build(keys, vals, config=_dur_cfg(tmp_path, engine))
-    up_k, up_v = keys[:40] + 0.5, np.arange(40, dtype=np.int64)
+    up_v = np.arange(40, dtype=np.int64)
     ix.upsert(up_k, up_v)
     oracle.upsert(up_k, up_v)
     ix.flush()                               # checkpointed prefix
@@ -153,6 +168,9 @@ def test_abandon_recover_round_trip(tmp_path, engine):
         wk, wv = oracle.items()
         np.testing.assert_array_equal(k, wk)
         np.testing.assert_array_equal(v, wv)
+        lv, lf = rx.lookup(wk)
+        assert lf.all()
+        np.testing.assert_array_equal(lv, wv)
         assert rx.engine == engine
         m = rx.metrics()
         assert m["counters"]["recovery.count"] == 1

@@ -1,9 +1,8 @@
-"""Data pipeline (DILI record store) + serving session table integration."""
+"""Datasets, the DILI record store, and the serving session table."""
 import numpy as np
 import pytest
 
 from repro.data.datasets import ALL_DATASETS, generate
-from repro.data.pipeline import StorePipeline, SyntheticLM
 from repro.data.record_store import RecordStore
 from repro.serve.sessions import SessionTable
 
@@ -15,15 +14,6 @@ def test_datasets_shape_and_determinism(name):
     assert len(a) == 5000
     assert np.all(np.diff(a) > 0)
     np.testing.assert_array_equal(a, b)
-
-
-def test_synthetic_lm_deterministic_and_learnable():
-    p = SyntheticLM(vocab=64, seq_len=16, batch=4, seed=5)
-    b1, b2 = p.batch_at(7), p.batch_at(7)
-    np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-    # labels mostly follow the permutation
-    match = (p.perm[b1["tokens"]] == b1["labels"]).mean()
-    assert match > 0.7
 
 
 def test_record_store_roundtrip():
@@ -43,18 +33,6 @@ def test_record_store_roundtrip():
     store.add(2e6, np.arange(7, dtype=np.int32))
     store.publish()
     np.testing.assert_array_equal(store.fetch(2e6), np.arange(7))
-
-
-def test_store_pipeline_batches():
-    rng = np.random.default_rng(7)
-    keys = np.unique(rng.uniform(0, 1e6, 200))
-    docs = [rng.integers(1, 50, 33).astype(np.int32) for _ in keys]
-    store = RecordStore(keys, docs)
-    pipe = StorePipeline(store, keys, seq_len=16, batch=8, seed=1)
-    b = pipe.batch_at(0)
-    assert b["tokens"].shape == (8, 16)
-    np.testing.assert_array_equal(pipe.batch_at(3)["tokens"],
-                                  pipe.batch_at(3)["tokens"])
 
 
 def test_session_table_admit_lookup_evict():

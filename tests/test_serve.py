@@ -32,6 +32,7 @@ from repro.serve import (AdaptiveBatchSizer, RejectedError, Request,
                          RequestBatcher, ServeConfig, ServeFrontend,
                          SessionTable, coalesce, open_loop, pow2_bucket)
 from repro.workloads.runner import WorkloadRunner
+from tests.conftest import ycsb_keys
 
 ENGINES = ("local", "pallas", "sharded")
 
@@ -271,8 +272,13 @@ def test_worker_spans_on_the_profiler_trace_nest_and_order(tmp_path):
         c.upsert([3.0], [30])
         c.lookup([3.0])
     finally:
-        jax.profiler.stop_trace()
-        fe.close()
+        # a call returns inside the worker's `serve.exec`; its
+        # `serve.dispatch` closes later, so drain and join the worker
+        # before the trace stops
+        try:
+            fe.close()
+        finally:
+            jax.profiler.stop_trace()
         ix.close()
     lines = [ev for ev in _host_events(tmp_path).values()
              if any(n == "serve.exec" for n, _, _ in ev)]
@@ -349,27 +355,30 @@ def test_worker_idle_time_in_stats(telemetry):
 
 # -- engine layer: the concurrency contract -----------------------------------
 
-def _client_program(fe, ci, keys, n, errors, writes_log):
-    """One seeded client stream: lookups/ranges over the loaded universe,
-    upserts/deletes over a client-private odd key range, with inline
-    read-your-acknowledged-writes assertions."""
+N_CLIENT_STEPS = 24
+
+
+def _client_program(fe, ci, keys, width, own, errors, writes_log):
+    """One seeded client stream: lookups/ranges of `width` over the loaded
+    keys, upserts/deletes over the client-private keys `own` (one per
+    step), with inline read-your-acknowledged-writes assertions."""
+    n = len(keys)
     try:
         c = fe.client(f"client-{ci}")
         r = np.random.default_rng(1000 + ci)
-        base = float(2 * n + 1 + 2_000_000 * ci)     # odd, disjoint, < 2^24
         live: list[tuple[float, int]] = []
-        for step in range(24):
+        for step in range(N_CLIENT_STEPS):
             choice = int(r.integers(0, 4))
             if choice == 0:
                 q = keys[r.integers(0, n, 8)]
                 v, f = c.lookup(q)
-                assert f.all(), "loaded even keys are never deleted"
+                assert f.all(), "loaded keys are never deleted"
             elif choice == 1:
                 lo = keys[r.integers(0, n, 4)]
-                ks, vs, cnt = c.range(lo, lo + 64.0)
+                ks, vs, cnt = c.range(lo, lo + width)
                 assert (cnt >= 1).all()              # lo itself is live
             elif choice == 2:
-                k, v = base + 2 * step, ci * 1000 + step
+                k, v = float(own[step]), ci * 1000 + step
                 c.upsert([k], [v])
                 live.append((k, v))
                 got = c.get(k)                       # read-your-writes
@@ -383,12 +392,29 @@ def _client_program(fe, ci, keys, n, errors, writes_log):
         errors.append((ci, e))
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_multi_client_oracle_equivalence(engine):
+def _multi_client_keys(shape, n, n_clients):
+    """The loaded keys, a range width, and each client's private write
+    keys: even integers with odd writes from disjoint ranges below 2^24,
+    or the local_hashed cells' YCSB record hashes with writes from
+    disjoint runs of the next records' hashes."""
+    if shape == "even":
+        keys = np.arange(0, 2 * n, 2, dtype=np.float64)
+        own = [2 * n + 1 + 2_000_000 * ci + 2 * np.arange(N_CLIENT_STEPS)
+               for ci in range(n_clients)]
+        return keys, 64.0, own
+    keys, new = ycsb_keys("hashed", n, n_clients * N_CLIENT_STEPS)
+    return keys, 32 * float(np.diff(keys).mean()), np.split(new, n_clients)
+
+
+@pytest.mark.parametrize("engine,shape", [
+    *(pytest.param(e, "even", id=e) for e in ENGINES),
+    *(pytest.param(e, "ycsb_hashed", id=f"{e}-ycsb_hashed")
+      for e in ("local", "sharded"))])
+def test_multi_client_oracle_equivalence(engine, shape):
     """>= 4 concurrent client streams; the journal's serialization
     replayed on a fresh index must match the served index bit-exactly."""
     n = 4000 if engine == "local" else 1500
-    keys = np.arange(0, 2 * n, 2, dtype=np.float64)
+    keys, width, own = _multi_client_keys(shape, n, 4)
     vals = np.arange(n, dtype=np.int64)
     cfg = IndexConfig(engine=engine)
     ix = LearnedIndex.build(keys, vals, config=cfg)
@@ -396,7 +422,8 @@ def test_multi_client_oracle_equivalence(engine):
     errors: list = []
     writes_log: dict = {}
     threads = [threading.Thread(target=_client_program,
-                                args=(fe, ci, keys, n, errors, writes_log))
+                                args=(fe, ci, keys, width, own[ci], errors,
+                                      writes_log))
                for ci in range(4)]
     for t in threads:
         t.start()

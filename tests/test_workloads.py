@@ -11,7 +11,10 @@ fault-injection test proves the diff actually bites.
 The differential contract uses the integer-key convention
 (tests/test_api_engines.py): integer-valued keys below 2^24 are exact
 under the pallas engine's f32 quantization, so every comparison is
-bit-exact on every engine — no tolerances.
+bit-exact on every engine — no tolerances.  The grid also replays the
+served cells' YCSB key sets (`bench/keys.py`): fnv64 hashes up to ~9.2e18,
+beyond f32's exact range (the pallas engine rejects them by design), and
+dense record numbers, where every insert lands at the tail.
 """
 import numpy as np
 import pytest
@@ -21,6 +24,7 @@ from repro.workloads import (PRESETS, SortedOracle, WorkloadDivergence,
                              WorkloadRunner, WorkloadSpec, generate_stream,
                              run_preset, sample_indices, stream_op_counts)
 from repro.workloads.distributions import ZetaCache, zipfian_ranks
+from tests.conftest import ycsb_keys
 
 ENGINES = ("local", "pallas", "sharded")
 UNIVERSE = np.arange(0, 6000, 2, dtype=np.float64)    # f32-exact even ints
@@ -261,21 +265,32 @@ GRID_PRESETS = ("ycsb_a", "ycsb_e", "dili_paper",
 # on (incremental splice-flatten + drift/tombstone retrains) — the grid is
 # what pins its exactness engine-by-engine
 MAINT_PRESETS = ("shift_fb_logn", "ttl_storm")
+# key shapes and the engines that serve them: the even-integer UNIVERSE,
+# and YCSB's key sets of GRID_RECORDS records, whose inserts are YCSB's
+# own (the next records), so the default odd-integer pool is never built
+GRID_SHAPES = {"even": ENGINES, "ycsb_hashed": ("local", "sharded"),
+               "ycsb_ordered": ENGINES}
+GRID_RECORDS = 3000
+GRID_CASES = [pytest.param(preset, engine, shape, id="-".join(
+                  [preset, engine] + ([shape] if shape != "even" else [])))
+              for shape, engines in GRID_SHAPES.items()
+              for preset in GRID_PRESETS for engine in engines]
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-@pytest.mark.parametrize("preset", GRID_PRESETS)
-def test_differential_grid(engine, preset):
+@pytest.mark.parametrize("preset,engine,shape", GRID_CASES)
+def test_differential_grid(preset, engine, shape):
     """Replay a seeded preset stream through the engine with per-batch
     oracle diffing (lookup hits AND misses, range contents, write/delete
     visibility, final items()).  strict=True: any divergence raises."""
     n_ops, bs = GRID_SIZES[engine]
     spec = PRESETS[preset].scaled(n_ops=n_ops, batch_size=bs, seed=13)
-    ix = LearnedIndex.build(UNIVERSE, config=IndexConfig(
+    keys, pool = ((UNIVERSE, None) if shape == "even" else
+                  ycsb_keys(shape.removeprefix("ycsb_"), GRID_RECORDS, n_ops))
+    ix = LearnedIndex.build(keys, config=IndexConfig(
         engine=engine, overlay_cap=512,
         maintenance=(MaintenanceConfig()
                      if preset in MAINT_PRESETS else None)))
-    report = WorkloadRunner(ix).run(generate_stream(spec, UNIVERSE),
+    report = WorkloadRunner(ix).run(generate_stream(spec, keys, pool),
                                     spec=spec)
     assert report.divergences == []
     assert spec.n_ops * 0.95 <= report.n_ops <= spec.n_ops
